@@ -295,6 +295,12 @@ def main(argv=None) -> int:
                     help="worker seats per host")
     ap.add_argument("--out", default="BENCH_multihost.json")
     args = ap.parse_args(argv)
+    from repro.runtime import wants_tpu
+
+    if wants_tpu():  # every host agent here would open the same chip
+        print("error: bench_multihost runs several host agents on one "
+              "machine; run it on the CPU", file=sys.stderr)
+        return 2
     if args.tiny:
         return run_tiny(args.out)
     counts = [int(c) for c in args.hosts.split(",") if c.strip()]

@@ -23,10 +23,10 @@ and one blocking host sync per trace.  The batched path produces
 *identical* ``TimingReport`` s to the per-trace path: padding requests are
 no-ops in the scan engine, so the bucket length never affects results.
 
-The TPU-native production implementation of engine (1) is the Pallas kernel
-in ``repro/kernels/dram_timing`` (blocked request streaming HBM->VMEM with
-bank state held in VMEM scratch across sequential grid steps; one grid row
-per batched trace).
+Every backend, the TPU included, times scan-routed traces with the
+vmapped ``lax.scan`` of (1).  The blocked Pallas kernel in
+``repro/kernels/dram_timing`` is not on this path: the TPU compiler refuses
+it (its ``(1, block)`` BlockSpec breaks the (8, 128) tiling rule).
 
 Memory-controller configuration lives on :class:`repro.core.dram.DRAMConfig`
 and threads through both engines:
@@ -96,19 +96,21 @@ def select_engine(trace_len: int, engine: str = "auto",
 # dispatch accounting
 # ---------------------------------------------------------------------------
 
-# Device-dispatch counters (scan-engine invocations; the fast engine is
-# host-side numpy and launches nothing).  ``benchmarks/bench_engine.py``
-# reports these for the sequential vs batched paths.
-_DISPATCH = dict(dispatches=0, traces=0, requests=0)
+# Device-dispatch counters (scan-engine invocations), plus the traces the
+# fast engine times on the host (it is numpy and launches nothing).
+# ``benchmarks/bench_engine.py`` reports these for the sequential vs
+# batched paths; serve seats report them per chunk.
+_DISPATCH = dict(dispatches=0, traces=0, requests=0, host_traces=0)
 
 
 def reset_dispatch_stats() -> None:
-    _DISPATCH.update(dispatches=0, traces=0, requests=0)
+    _DISPATCH.update(dispatches=0, traces=0, requests=0, host_traces=0)
 
 
 def dispatch_stats() -> dict:
     """Counters since the last reset: device ``dispatches``, ``traces``
-    timed through them, and true (unpadded) ``requests`` simulated."""
+    timed through them, true (unpadded) ``requests`` simulated, and
+    ``host_traces`` timed by the host fast engine."""
     return dict(_DISPATCH)
 
 
@@ -431,6 +433,7 @@ def simulate_channel_fast(trace: Trace, cfg: DRAMConfig) -> TimingReport:
     cls = classify_fast(bank, row, cfg.nbanks, cfg.page_open)
     t = cfg.timing_cycles()
     cycles, hits, misses, conflicts = _fast_cycles(trace.n, cls, bank, cfg, t)
+    _DISPATCH["host_traces"] += 1
     return _channel_report(trace, cfg, cycles, hits, misses, conflicts)
 
 
@@ -467,6 +470,7 @@ def _simulate_fast_batch(traces: list[Trace], cfg: DRAMConfig) -> list[TimingRep
     bit-for-bit."""
     batch = TraceBatch.from_traces(traces, cfg, pad_batch=False)
     B, L = batch.bank.shape  # pad_batch=False keeps B == len(traces)
+    _DISPATCH["host_traces"] += sum(tr.n > 0 for tr in traces)
     valid = np.arange(L)[None, :] < batch.lengths[:, None]
     cls = _classify_fast_batch(batch.bank, batch.row, valid, cfg.nbanks,
                                cfg.page_open)

@@ -5,8 +5,7 @@ processing that decides values, update counts and changed sets — run
 host-side in numpy by default (the seed's design: trace generation as
 offline preprocessing, mirroring the paper's C++ environment).  This
 module provides the ``device`` engine: the same semantics expressed as
-fused JAX dispatches built on the repo's kernels
-(``kernels.edge_update.scatter_min``, ``kernels.spmv.spmv_edges``), with
+fused, scatter-free JAX dispatches (see *reduce plans* below), with
 graph state (value vectors, frontier bitmaps) resident on the device
 across iterations.  Per iteration only small products cross the host
 boundary — a changed bitmap, per-partition update counts, per-interval
@@ -27,15 +26,14 @@ Byte identity contract (tests/test_semexec.py):
   values match to float tolerance (segment-sum association order differs
   from ``np.add.at``).
 
-Kernel selection: on TPU backends the device steps call the kernel
-wrappers (``use_pallas=None, interpret=False`` — compiled Pallas).  On
-CPU, XLA lowers scatters to a serial loop roughly an order of magnitude
-slower than numpy's ``ufunc.at``, so the steps instead use *reduce
-plans*: the edge layouts are static across iterations, so every
-per-segment min/sum/max is precomputed host-side into degree-class
-gather tables (a bucketed-ELL layout of the reduction) and evaluated as
-pure gathers + dense row reductions — no scatter anywhere in the
-per-iteration dispatch.  See :func:`build_reduce_plan`.
+Reductions: every backend runs the same program.  The edge layouts are
+static across iterations, so every per-segment min/sum/max is
+precomputed host-side into a *reduce plan* — degree-class gather tables
+(a bucketed-ELL layout of the reduction) — and evaluated as pure
+gathers + dense row reductions, with no scatter over edges.  See
+:func:`build_reduce_plan`.  (The Pallas
+kernels under ``repro.kernels`` are not on this path: the TPU compiler
+refuses their 1-D gathers.)
 
 ``resolve_engine`` maps a requested engine to the effective one: combos
 without a device formulation fall back to numpy with a one-time warning.
@@ -52,10 +50,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.hostcache import ARTIFACTS
-from repro.kernels._platform import on_tpu
-from repro.kernels.edge_update.ops import scatter_min
-from repro.kernels.spmv.ops import spmv_edges
-from repro.kernels.spmv.ref import to_ell
 
 ENGINES = ("numpy", "device")
 
@@ -69,7 +63,7 @@ SUPPORTED: dict[str, frozenset] = {
     "foregraph": frozenset({"bfs", "wcc", "pr"}),
 }
 
-_EDGE_BLOCK = 1024  # scatter_min's Pallas block; edge arrays pad to it
+_EDGE_BLOCK = 1024  # edge arrays pad to a multiple of this
 
 _FALLBACK_WARNED: set[tuple[str, str]] = set()
 
@@ -142,23 +136,16 @@ def _acc_weight(problem_name: str, src: np.ndarray,
     raise ValueError(problem_name)
 
 
-def _maybe_ell(src: np.ndarray, dst: np.ndarray, w: np.ndarray, n: int):
-    """ELL layout for the Pallas SpMV — only worth building on TPU."""
-    if not on_tpu():
-        return None
-    idx, val = to_ell(src, dst, w, n)
-    return (jnp.asarray(idx), jnp.asarray(val))
-
-
 # ---------------------------------------------------------------------------
-# reduce plans: scatter-free segment reductions for the CPU backend
+# reduce plans: scatter-free segment reductions
 # ---------------------------------------------------------------------------
 #
 # XLA's CPU scatter lowering is a serial per-element loop (~8x slower than
-# numpy's ufunc.at on this class of workload), which would sink the whole
-# point of the device engine.  But the segment-id arrays here (destination
-# vertex, partition id, run id) are *static* across iterations, so the
-# reduction structure can be precomputed host-side once per layout:
+# numpy's ufunc.at on this class of workload), and the TPU compiler refuses
+# the Pallas scatter kernels' 1-D gathers.  But the segment-id arrays here
+# (destination vertex, partition id, run id) are *static* across
+# iterations, so the reduction structure can be precomputed host-side once
+# per layout:
 #
 # - sort edge positions by segment id (stable), bucket the non-empty
 #   segments by power-of-two degree class,
@@ -225,141 +212,100 @@ def apply_reduce_plan(plan, cand, kind: str):
     return jnp.take(cat, inv, axis=0)
 
 
-def _plans_or_none(build):
-    """Build reduce plans on CPU; TPU keeps the Pallas/segment-op path."""
-    return None if on_tpu() else build()
-
-
 # ---------------------------------------------------------------------------
 # jitted per-iteration steps
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("use_filter", "use_skip", "combine",
-                                   "k", "runs"))
-def _hitgraph_min_step(values, active, proc, src, dst, delta, part, jid,
-                       run_id, run_j, plans, *, use_filter, use_skip,
-                       combine, k, runs):
+@partial(jax.jit, static_argnames=("use_filter", "use_skip", "combine"))
+def _hitgraph_min_step(values, active, proc, src, delta, part, plans, *,
+                       use_filter, use_skip, combine):
     """One HitGraph scatter+gather iteration, fused: global masked
-    scatter-min plus the per-destination-partition update counts the trace
+    segment-min plus the per-destination-partition update counts the trace
     assembly needs.  ``kept`` reproduces the model's update-filtering
     (active-source bitmap) and partition-skipping masks; with update
     combining the count per partition j is the number of (source
     partition, destination) runs containing a kept edge — dst is sorted
     within each routed block, so runs == unique destinations."""
-    valid = src >= 0
-    kept = valid
+    kept = src >= 0
     if use_skip:
         kept &= jnp.take(proc, jnp.maximum(part, 0))
     if use_filter:
         kept &= jnp.take(active, jnp.maximum(src, 0))
-    if plans is None:
-        acc = scatter_min(src, dst, delta, values, mask=kept,
-                          use_pallas=None, interpret=False)
-    else:
-        sv = jnp.take(values, jnp.maximum(src, 0))
-        cand = jnp.where(kept, sv + delta, jnp.inf)
-        acc = apply_reduce_plan(plans["dst"], cand, "min")
-    new = jnp.minimum(values, acc)
-    changed = acc < values
+    sv = jnp.take(values, jnp.maximum(src, 0))
+    cand = jnp.where(kept, sv + delta, jnp.inf)
+    acc = apply_reduce_plan(plans["dst"], cand, "min")
     ki = kept.astype(jnp.int32)
     if combine:
-        if plans is None:
-            run_has = jax.ops.segment_max(ki, run_id, num_segments=runs)
-            nupd = jax.ops.segment_sum(run_has, run_j, num_segments=k)
-        else:
-            run_has = apply_reduce_plan(plans["run"], ki, "max")
-            nupd = apply_reduce_plan(plans["runj"], run_has, "sum")
-    elif plans is None:
-        nupd = jax.ops.segment_sum(ki, jid, num_segments=k)
+        run_has = apply_reduce_plan(plans["run"], ki, "max")
+        nupd = apply_reduce_plan(plans["runj"], run_has, "sum")
     else:
         nupd = apply_reduce_plan(plans["jid"], ki, "sum")
-    return new, changed, nupd
+    return jnp.minimum(values, acc), acc < values, nupd
 
 
 @jax.jit
-def _jacobi_min_step(values, src, dst, delta, plans):
+def _jacobi_min_step(values, src, delta, plan):
     """ThunderGP's synchronous iteration: the per-(partition, chunk)
-    partial accumulations combine to exactly the global scatter-min
+    partial accumulations combine to exactly the global segment-min
     (disjoint destination intervals, Jacobi source snapshot)."""
-    if plans is None:
-        acc = scatter_min(src, dst, delta, values,
-                          use_pallas=None, interpret=False)
-    else:
-        sv = jnp.take(values, jnp.maximum(src, 0))
-        cand = jnp.where(src >= 0, sv + delta, jnp.inf)
-        acc = apply_reduce_plan(plans, cand, "min")
+    sv = jnp.take(values, jnp.maximum(src, 0))
+    cand = jnp.where(src >= 0, sv + delta, jnp.inf)
+    acc = apply_reduce_plan(plan, cand, "min")
     return jnp.minimum(values, acc), jnp.any(acc < values)
 
 
 @jax.jit
-def _acc_step(values, src, dst, w, ell, base, scale, plans):
+def _acc_step(values, src, w, base, scale, plan):
     """Shared accumulation iteration: new = base + scale * A @ values,
-    with A[dst, src] = w_eff.  Padding edges carry src=0 / w=0 and
-    contribute exactly 0."""
-    if plans is None:
-        y = spmv_edges(src, dst, w, values, values.shape[0], ell=ell,
-                       use_pallas=None, interpret=False)
-    else:
-        y = apply_reduce_plan(plans, w * jnp.take(values, src), "sum")
+    with A[dst, src] = w_eff."""
+    y = apply_reduce_plan(plan, w * jnp.take(values, src), "sum")
     return base + scale * y
 
 
 @jax.jit
-def _gs_min_step(values, esrc, einv, ud, delta, plans):
+def _gs_min_step(values, esrc, ud, delta, plan):
     """One AccuGraph partition under Gauss-Seidel (live values): segment
     min over the partition's unique destinations.  Padding edges carry
     cand=+inf and padding ud slots point at vertex 0 with acc=+inf, both
     exact no-ops."""
     sv = jnp.take(values, jnp.maximum(esrc, 0))
     cand = jnp.where(esrc >= 0, sv + delta, jnp.inf)
-    acc = (jax.ops.segment_min(cand, einv, num_segments=ud.shape[0])
-           if plans is None else apply_reduce_plan(plans, cand, "min"))
+    acc = apply_reduce_plan(plan, cand, "min")
     changed = acc < jnp.take(values, ud)
     return values.at[ud].min(acc), changed
 
 
 @jax.jit
-def _gs_acc_step(values, snapshot, esrc, einv, ud, ew, scale, plans):
+def _gs_acc_step(values, snapshot, esrc, ud, ew, scale, plan):
     """One AccuGraph partition of an accumulation iteration (reads the
     pre-iteration snapshot, adds into the base-initialised values)."""
     sv = jnp.take(snapshot, jnp.maximum(esrc, 0))
     cand = jnp.where(esrc >= 0, sv * ew, jnp.float32(0.0))
-    acc = (jax.ops.segment_sum(cand, einv, num_segments=ud.shape[0])
-           if plans is None else apply_reduce_plan(plans, cand, "sum"))
+    acc = apply_reduce_plan(plan, cand, "sum")
     return values.at[ud].add(scale * acc)
 
 
-@partial(jax.jit, static_argnames=("q",))
-def _fg_min_step(values, asrc, adst, bsrc, bdst, csrc, cdst, delta, ipq,
-                 plans, *, q):
+@jax.jit
+def _fg_min_step(values, asrc, bsrc, csrc, delta, plans):
     """One ForeGraph source-interval visit, fused into three sequential
-    scatter-mins that reproduce the shard-order Gauss-Seidel exactly:
+    segment-mins that reproduce the shard-order Gauss-Seidel exactly:
     shards (i, j<i) read the still-pristine source interval i and write
     disjoint intervals; shard (i, i) reads pre-state and writes interval
     i; shards (i, j>i) read the post-(i,i) interval i.  Returns the
     values and per-interval changed flags (the dirty bits)."""
 
-    def sub(v, s, d, plan):
-        if plan is None:
-            dl = jnp.full(s.shape, delta, v.dtype)
-            acc = scatter_min(s, d, dl, v, use_pallas=None, interpret=False)
-        else:
-            sv = jnp.take(v, jnp.maximum(s, 0))
-            cand = jnp.where(s >= 0, sv + delta, jnp.inf)
-            acc = apply_reduce_plan(plan, cand, "min")
+    def sub(v, s, plan):
+        sv = jnp.take(v, jnp.maximum(s, 0))
+        cand = jnp.where(s >= 0, sv + delta, jnp.inf)
+        acc = apply_reduce_plan(plan, cand, "min")
         return jnp.minimum(v, acc), acc < v
 
-    pa, pb, pc = ((None, None, None) if plans is None
-                  else (plans["a"], plans["b"], plans["c"]))
-    v1, c1 = sub(values, asrc, adst, pa)
-    v2, c2 = sub(v1, bsrc, bdst, pb)
-    v3, c3 = sub(v2, csrc, cdst, pc)
+    v1, c1 = sub(values, asrc, plans["a"])
+    v2, c2 = sub(v1, bsrc, plans["b"])
+    v3, c3 = sub(v2, csrc, plans["c"])
     changed = (c1 | c2 | c3).astype(jnp.int32)
-    flags = (jax.ops.segment_max(changed, ipq, num_segments=q)
-             if plans is None
-             else apply_reduce_plan(plans["ipq"], changed, "max"))
-    return v3, flags
+    return v3, apply_reduce_plan(plans["ipq"], changed, "max")
 
 
 # ---------------------------------------------------------------------------
@@ -399,26 +345,18 @@ def _build_hitgraph_min(g, problem, prep, k: int, ivl: int) -> dict:
         runs = 1
         run_j = np.zeros(0, dtype=np.int32)
     L = _block_len(m)
-    pdst = _pad_to(gdst, L, 0, np.int32)
-    pjid = _pad_to(gjid, L, 0, np.int32)
-    prun = _pad_to(run_id, L, 0, np.int32)
     # padding edges land in segment 0 / run 0 of each plan with kept=0
     # candidates (inf for the min, 0 for the counts) — exact no-ops
-    plans = _plans_or_none(lambda: dict(
-        dst=build_reduce_plan(pdst, g.n),
-        run=build_reduce_plan(prun, max(runs, 1)),
+    plans = dict(
+        dst=build_reduce_plan(_pad_to(gdst, L, 0, np.int32), g.n),
+        run=build_reduce_plan(_pad_to(run_id, L, 0, np.int32), runs),
         runj=build_reduce_plan(run_j, k),
-        jid=build_reduce_plan(pjid, k),
-    ))
+        jid=build_reduce_plan(_pad_to(gjid, L, 0, np.int32), k),
+    )
     return dict(
         src=jnp.asarray(_pad_to(gsrc, L, -1, np.int32)),
-        dst=jnp.asarray(pdst),
         delta=jnp.asarray(_pad_to(delta, L, 0.0, np.float32)),
         part=jnp.asarray(_pad_to(gpart, L, 0, np.int32)),
-        jid=jnp.asarray(pjid),
-        run_id=jnp.asarray(prun),
-        run_j=jnp.asarray(_pad_to(run_j, max(runs, 1), 0, np.int32)),
-        runs=max(runs, 1),
         plans=plans,
     )
 
@@ -438,10 +376,8 @@ def _build_hitgraph_acc(g, problem, parts, k: int, ivl: int) -> dict:
     changed_j = [ud_all[cuts[j]: cuts[j + 1]] for j in range(k)]
     return dict(
         src=jnp.asarray(g.src.astype(np.int32)),
-        dst=jnp.asarray(g.dst.astype(np.int32)),
         w=jnp.asarray(w_eff),
-        ell=_maybe_ell(g.src, g.dst, w_eff, g.n),
-        plan=_plans_or_none(lambda: build_reduce_plan(g.dst, g.n)),
+        plan=build_reduce_plan(g.dst, g.n),
         nupd_plain=nupd_plain,
         nupd_combine=nupd_combine,
         changed_j=changed_j,
@@ -454,7 +390,6 @@ class HitGraphDevice:
     def __init__(self, g, problem, prep, parts, k: int, ivl: int,
                  sort_opt: bool, weighted: bool,
                  filter_opt: bool, skip_opt: bool, combine_opt: bool):
-        self.k = k
         self.filter_opt = filter_opt
         self.skip_opt = skip_opt
         self.combine_opt = combine_opt
@@ -479,16 +414,15 @@ class HitGraphDevice:
         lay = self.lay
         new, changed, nupd = _hitgraph_min_step(
             values_dev, jnp.asarray(active), jnp.asarray(proc),
-            lay["src"], lay["dst"], lay["delta"], lay["part"], lay["jid"],
-            lay["run_id"], lay["run_j"], lay["plans"],
+            lay["src"], lay["delta"], lay["part"], lay["plans"],
             use_filter=self.filter_opt, use_skip=self.skip_opt,
-            combine=self.combine_opt, k=self.k, runs=lay["runs"])
+            combine=self.combine_opt)
         return new, np.asarray(changed), np.asarray(nupd).astype(np.int64)
 
     def acc_step(self, values_dev):
         lay = self.lay
-        return _acc_step(values_dev, lay["src"], lay["dst"], lay["w"],
-                         lay["ell"], self.base, self.scale, lay["plan"])
+        return _acc_step(values_dev, lay["src"], lay["w"], self.base,
+                         self.scale, lay["plan"])
 
     def nupd_static(self) -> np.ndarray:
         return self.lay["nupd_combine" if self.combine_opt else "nupd_plain"]
@@ -503,23 +437,21 @@ class HitGraphDevice:
 
 
 def _build_accugraph(g, problem, part_edges, k: int, ivl: int) -> dict:
-    esrc, einv, ud, ew, plan = [], [], [], [], []
+    esrc, ud, ew, plan = [], [], [], []
     ud_host, u_count = [], []
     for p in range(k):
         src, _dst, udp, inv = part_edges[p]
         E = _pow2(len(src))
         U = _pow2(max(len(udp), 1), lo=1)
-        pinv = _pad_to(inv, E, 0, np.int32)
         esrc.append(jnp.asarray(_pad_to(src, E, -1, np.int32)))
-        einv.append(jnp.asarray(pinv))
         ud.append(jnp.asarray(_pad_to(udp, U, 0, np.int32)))
-        plan.append(_plans_or_none(lambda: build_reduce_plan(pinv, U)))
+        plan.append(build_reduce_plan(_pad_to(inv, E, 0, np.int32), U))
         ud_host.append(np.asarray(udp))
         u_count.append(len(udp))
         if problem.kind == "acc":
             w_eff = _acc_weight(problem.name, src, None, g.degrees_out)
             ew.append(jnp.asarray(_pad_to(w_eff, E, 0.0, np.float32)))
-    return dict(esrc=esrc, einv=einv, ud=ud, ew=ew, plan=plan,
+    return dict(esrc=esrc, ud=ud, ew=ew, plan=plan,
                 ud_host=ud_host, u_count=u_count)
 
 
@@ -544,8 +476,7 @@ class AccuGraphDevice:
         if lay["u_count"][p] == 0:
             return values_dev, np.zeros(0, dtype=bool)
         new, changed = _gs_min_step(values_dev, lay["esrc"][p],
-                                    lay["einv"][p], lay["ud"][p], self.delta,
-                                    lay["plan"][p])
+                                    lay["ud"][p], self.delta, lay["plan"][p])
         return new, np.asarray(changed)[: lay["u_count"][p]]
 
     def acc_step(self, values_dev, snapshot_dev, p: int):
@@ -553,8 +484,8 @@ class AccuGraphDevice:
         if lay["u_count"][p] == 0:
             return values_dev
         return _gs_acc_step(values_dev, snapshot_dev, lay["esrc"][p],
-                            lay["einv"][p], lay["ud"][p], lay["ew"][p],
-                            self.scale, lay["plan"][p])
+                            lay["ud"][p], lay["ew"][p], self.scale,
+                            lay["plan"][p])
 
 
 # ---------------------------------------------------------------------------
@@ -576,12 +507,10 @@ def _build_thundergp(g, problem, prep, k: int, p: int, ivl: int) -> dict:
             w = None
         delta = _min_delta(problem.name, w, m)
         L = _block_len(m)
-        pdst = _pad_to(gdst, L, 0, np.int32)
         return dict(
             src=jnp.asarray(_pad_to(gsrc, L, -1, np.int32)),
-            dst=jnp.asarray(pdst),
             delta=jnp.asarray(_pad_to(delta, L, 0.0, np.float32)),
-            plan=_plans_or_none(lambda: build_reduce_plan(pdst, g.n)),
+            plan=build_reduce_plan(_pad_to(gdst, L, 0, np.int32), g.n),
         )
     if problem.name == "spmv":
         w = np.concatenate(
@@ -589,10 +518,8 @@ def _build_thundergp(g, problem, prep, k: int, p: int, ivl: int) -> dict:
     else:
         w = None
     w_eff = _acc_weight(problem.name, gsrc, w, g.degrees_out)
-    return dict(src=jnp.asarray(gsrc), dst=jnp.asarray(gdst),
-                w=jnp.asarray(w_eff),
-                ell=_maybe_ell(gsrc, gdst, w_eff, g.n),
-                plan=_plans_or_none(lambda: build_reduce_plan(gdst, g.n)))
+    return dict(src=jnp.asarray(gsrc), w=jnp.asarray(w_eff),
+                plan=build_reduce_plan(gdst, g.n))
 
 
 class ThunderGPDevice:
@@ -612,14 +539,14 @@ class ThunderGPDevice:
 
     def min_step(self, values_dev):
         lay = self.lay
-        new, anyc = _jacobi_min_step(values_dev, lay["src"], lay["dst"],
-                                     lay["delta"], lay["plan"])
+        new, anyc = _jacobi_min_step(values_dev, lay["src"], lay["delta"],
+                                     lay["plan"])
         return new, bool(anyc)
 
     def acc_step(self, values_dev):
         lay = self.lay
-        return _acc_step(values_dev, lay["src"], lay["dst"], lay["w"],
-                         lay["ell"], self.base, self.scale, lay["plan"])
+        return _acc_step(values_dev, lay["src"], lay["w"], self.base,
+                         self.scale, lay["plan"])
 
 
 # ---------------------------------------------------------------------------
@@ -637,10 +564,8 @@ def _build_foregraph(g, problem, sizes, shard_edges, interval: int,
         gdst = (np.concatenate([d for _, d in pairs]).astype(np.int32)
                 if pairs else np.zeros(0, dtype=np.int32))
         w_eff = _acc_weight(problem.name, gsrc, None, g.degrees_out)
-        return dict(src=jnp.asarray(gsrc), dst=jnp.asarray(gdst),
-                    w=jnp.asarray(w_eff),
-                    ell=_maybe_ell(gsrc, gdst, w_eff, g.n),
-                    plan=_plans_or_none(lambda: build_reduce_plan(gdst, g.n)))
+        return dict(src=jnp.asarray(gsrc), w=jnp.asarray(w_eff),
+                    plan=build_reduce_plan(gdst, g.n))
 
     def pack(i: int, js: list[int]):
         es = [shard_edges[(i, j)] for j in js if sizes[i, j]]
@@ -649,23 +574,17 @@ def _build_foregraph(g, problem, sizes, shard_edges, interval: int,
         dst = (np.concatenate([d for _, d in es]).astype(np.int32)
                if es else np.zeros(0, dtype=np.int32))
         E = _pow2(len(src))
-        pdst = _pad_to(dst, E, 0, np.int32)
-        plan = _plans_or_none(lambda: build_reduce_plan(pdst, g.n))
         return (jnp.asarray(_pad_to(src, E, -1, np.int32)),
-                jnp.asarray(pdst)), plan
+                build_reduce_plan(_pad_to(dst, E, 0, np.int32), g.n))
 
-    ipq_np = (np.arange(g.n) // interval).astype(np.int32)
-    ipq_plan = _plans_or_none(lambda: build_reduce_plan(ipq_np, q))
+    ipq = build_reduce_plan((np.arange(g.n) // interval).astype(np.int32), q)
     abc, plans = [], []
     for i in range(q):
-        a, pa = pack(i, list(range(i)))
-        b, pb = pack(i, [i])
-        c, pc = pack(i, list(range(i + 1, q)))
-        abc.append(a + b + c)
-        plans.append(None if pa is None
-                     else dict(a=pa, b=pb, c=pc, ipq=ipq_plan))
-    ipq = jnp.asarray(ipq_np)
-    return dict(abc=abc, ipq=ipq, plans=plans)
+        (a, pa), (b, pb), (c, pc) = (pack(i, list(range(i))), pack(i, [i]),
+                                     pack(i, list(range(i + 1, q))))
+        abc.append((a, b, c))
+        plans.append(dict(a=pa, b=pb, c=pc, ipq=ipq))
+    return dict(abc=abc, plans=plans)
 
 
 class ForeGraphDevice:
@@ -678,7 +597,6 @@ class ForeGraphDevice:
 
     def __init__(self, g, problem, sizes, shard_edges, interval: int,
                  q: int):
-        self.q = q
         self.lay = ARTIFACTS.get_or_build(
             (g.fingerprint, "semexec.foregraph", interval, problem.name),
             lambda: _build_foregraph(g, problem, sizes, shard_edges,
@@ -694,10 +612,10 @@ class ForeGraphDevice:
     def min_step(self, values_dev, i: int):
         lay = self.lay
         new, flags = _fg_min_step(values_dev, *lay["abc"][i], self.delta,
-                                  lay["ipq"], lay["plans"][i], q=self.q)
+                                  lay["plans"][i])
         return new, np.asarray(flags).astype(bool)
 
     def acc_step(self, values_dev):
         lay = self.lay
-        return _acc_step(values_dev, lay["src"], lay["dst"], lay["w"],
-                         lay["ell"], self.base, self.scale, lay["plan"])
+        return _acc_step(values_dev, lay["src"], lay["w"], self.base,
+                         self.scale, lay["plan"])

@@ -424,7 +424,8 @@ class RemoteWorkerPool:
                     completions.append(
                         (task.future,
                          dict(records=records,
-                              hostcache=body.get("hostcache") or {}), False))
+                              hostcache=body.get("hostcache") or {},
+                              device=body.get("device") or {}), False))
             elif isinstance(body.get("lost"), dict):
                 # the host's *local* pool lost a worker: forward the loss
                 # structure so scheduler recovery is host-transparent
@@ -633,7 +634,9 @@ class WorkerHostAgent:
 
     def _ensure_pool(self):
         if self.pool is None:
+            from repro.runtime import check_device_seats
             from repro.serve import worker as worker_mod
+            check_device_seats(self.seats)
             self.pool = WorkerPool(self.seats,
                                    initializer=worker_mod.init_worker,
                                    task_deadline_s=self.worker_deadline_s)
@@ -751,7 +754,8 @@ class WorkerHostAgent:
             out = fut.result()
             body = dict(host_id=host_id, chunk=chunk_id, ok=True,
                         records=out["records"],
-                        hostcache=out.get("hostcache") or {})
+                        hostcache=out.get("hostcache") or {},
+                        device=out.get("device") or {})
         except CancelledError:
             return
         except WorkerLost as e:

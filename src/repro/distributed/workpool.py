@@ -75,7 +75,7 @@ class _Slot:
     """One worker seat: a (re)spawnable process plus its supervision state."""
 
     __slots__ = ("id", "proc", "conn", "ready", "last_hb", "task", "t_task",
-                 "respawns", "retired", "spawn_after")
+                 "respawns", "retired", "spawn_after", "info")
 
     def __init__(self, slot_id: int):
         self.id = slot_id
@@ -88,11 +88,13 @@ class _Slot:
         self.respawns = 0
         self.retired = False
         self.spawn_after = 0.0
+        self.info = None  # what the initializer returned (e.g. device info)
 
 
 def _worker_main(conn, initializer, initargs, heartbeat_s: float) -> None:
     """Worker process body: init, then heartbeat + execute loop.  All sends
-    share one lock so heartbeats never interleave mid-pickle with results."""
+    share one lock so heartbeats never interleave mid-pickle with results.
+    The initializer's return value rides on the ``ready`` message."""
     send_lock = threading.Lock()
 
     def send(msg) -> None:
@@ -102,13 +104,14 @@ def _worker_main(conn, initializer, initargs, heartbeat_s: float) -> None:
             except (OSError, ValueError):
                 os._exit(3)  # parent is gone; nothing left to serve
 
+    info = None
     if initializer is not None:
         try:
-            initializer(*initargs)
+            info = initializer(*initargs)
         except BaseException:
             traceback.print_exc()
             os._exit(4)
-    send(("ready",))
+    send(("ready", info))
 
     def beat() -> None:
         while True:
@@ -200,7 +203,9 @@ class WorkerPool:
                         alive=alive,
                         retired=sum(s.retired for s in self._slots),
                         workers_lost=self._workers_lost,
-                        respawns=self._respawns)
+                        respawns=self._respawns,
+                        seats=[s.info for s in self._slots
+                               if s.ready and s.info is not None])
 
     def shutdown(self, wait: bool = True, cancel_pending: bool = False,
                  grace_s: float | None = None) -> None:
@@ -343,6 +348,7 @@ class WorkerPool:
         kind = msg[0]
         if kind == "ready":
             s.ready = True
+            s.info = msg[1]
             s.last_hb = time.monotonic()
         elif kind == "hb":
             s.last_hb = time.monotonic()

@@ -25,7 +25,6 @@ import numpy as np
 from repro.graph.structure import Graph
 
 DAMPING = 0.85
-INF = jnp.float32(jnp.inf)
 
 
 @dataclasses.dataclass(frozen=True)

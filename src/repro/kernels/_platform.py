@@ -10,7 +10,8 @@ Historically each ops module resolved the ``None`` defaults on its own; the
 logic now lives here so every kernel picks the same policy and CPU CI
 exercises the Pallas path automatically:
 
-- On a TPU backend the Pallas kernel is compiled (``interpret=False``).
+- On a TPU backend the Pallas kernel is compiled (``interpret=False``);
+  only a caller's explicit ``interpret=True`` interprets it there.
 - Anywhere else (CPU CI, laptops) the Pallas kernel still runs, via
   ``interpret=True`` — same program, interpreted — so tier-1 covers it.
 - Passing ``interpret=True`` explicitly also opts into the Pallas path,

@@ -1,9 +1,11 @@
 """Public ops: min-propagation scatter over edges.
 
 ``scatter_min`` is the array-level primitive (jnp in/out, safe to call from
-inside an outer ``jax.jit`` — the semexec device path embeds it in its fused
-per-iteration steps); ``relax_step`` is the Graph-level convenience wrapper
-kept for the workload benches.
+inside an outer ``jax.jit``); ``relax_step`` is the Graph-level convenience
+wrapper kept for the workload benches.  Neither is on the simulator's path:
+the semexec device engine reduces through reduce plans
+(``repro.core.semexec``), because the TPU compiler refuses this kernel's
+1-D gather ("Only 2D gather is supported").
 """
 from __future__ import annotations
 

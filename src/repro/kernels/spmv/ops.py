@@ -1,9 +1,10 @@
 """Public SpMV ops.
 
 ``spmv_edges`` is the array-level primitive (jnp in/out, safe to embed in an
-outer ``jax.jit`` — the semexec device path uses it for every accumulate-kind
-problem: PR contributions, SpMV itself); ``spmv`` is the Graph-level wrapper
-kept for the workload benches.
+outer ``jax.jit``); ``spmv`` is the Graph-level wrapper kept for the
+workload benches.  Neither is on the simulator's path: the semexec device
+engine accumulates through reduce plans (``repro.core.semexec``), because
+the TPU compiler refuses this kernel's 1-D gather.
 """
 from __future__ import annotations
 

@@ -7,9 +7,8 @@ process sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512``.
 from __future__ import annotations
 
 import jax
-
-
 import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,11 +21,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     devices = jax.devices()[: int(np.prod(shape))]
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_dev_mesh(n_devices: int | None = None, model: int | None = None):
     """Small mesh over the locally available devices (tests / examples)."""
     n = n_devices or len(jax.devices())
     model = model or (2 if n % 2 == 0 and n > 1 else 1)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

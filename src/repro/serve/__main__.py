@@ -102,18 +102,22 @@ def _serve(args: argparse.Namespace) -> int:
                 host=whost, port=wport, fault_plan=fault_plan,
                 task_deadline_s=args.worker_deadline or None)
 
-    server = SweepServer(
-        host=args.host, port=args.port,
-        cache_dir=args.cache or None,
-        workers=args.workers, mode=args.mode, policy=policy,
-        chunk_size=args.chunk_size, trace_hashes=args.trace_hashes,
-        quiet=args.quiet,
-        pool_factory=pool_factory,
-        poison_threshold=args.poison_threshold,
-        fault_plan=fault_plan,
-        worker_deadline_s=args.worker_deadline or None,
-        resume=not args.no_resume,
-    )
+    try:
+        server = SweepServer(
+            host=args.host, port=args.port,
+            cache_dir=args.cache or None,
+            workers=args.workers, mode=args.mode, policy=policy,
+            chunk_size=args.chunk_size, trace_hashes=args.trace_hashes,
+            quiet=args.quiet,
+            pool_factory=pool_factory,
+            poison_threshold=args.poison_threshold,
+            fault_plan=fault_plan,
+            worker_deadline_s=args.worker_deadline or None,
+            resume=not args.no_resume,
+        )
+    except ValueError as e:  # e.g. two device seats on one chip
+        print(f"error: {e}", file=sys.stderr)
+        return 2
     server.install_signal_handlers()
     server.start()
     if args.port_file:
@@ -162,10 +166,13 @@ def worker_main(argv: list[str] | None = None) -> int:
 
     log = (lambda event, **kw: None) if args.quiet else (
         lambda event, **kw: jlog(event, **kw))
-    outcome = run_worker_host(args.connect, seats=max(1, args.seats),
-                              name=args.name or None,
-                              worker_deadline_s=args.worker_deadline or None,
-                              log=log)
+    try:
+        outcome = run_worker_host(
+            args.connect, seats=max(1, args.seats), name=args.name or None,
+            worker_deadline_s=args.worker_deadline or None, log=log)
+    except ValueError as e:  # e.g. two device seats on one chip
+        print(f"error: {e}", file=sys.stderr)
+        return 2
     return 0 if outcome == "shutdown" else 1
 
 
@@ -264,7 +271,8 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--cache", default="results/sweep_cache",
                     help="result cache directory ('' disables caching)")
     ap.add_argument("--workers", type=int, default=2,
-                    help="persistent spawn-worker pool size")
+                    help="persistent spawn-worker pool size (one per chip "
+                         "on a TPU host: JAX_PLATFORMS=tpu refuses more)")
     ap.add_argument("--mode", default="batch", choices=("scenario", "batch"))
     ap.add_argument("--chunk-size", type=int, default=4,
                     help="scenarios per worker dispatch")

@@ -72,6 +72,7 @@ from typing import Callable
 from concurrent.futures import Future
 
 from repro.distributed.workpool import WorkerLost, WorkerPool
+from repro.runtime import check_device_seats
 from repro.serve import worker as worker_mod
 from repro.serve.journal import JobJournal
 from repro.serve.metrics import Metrics
@@ -217,6 +218,8 @@ class SweepScheduler:
         self.log = log or (lambda event, **kw: None)
         self.t_start = time.time()
 
+        if pool_factory is None:
+            check_device_seats(max(1, workers))
         self.pool = (pool_factory() if pool_factory is not None
                      else WorkerPool(max(1, workers),
                                      initializer=worker_mod.init_worker,
@@ -505,6 +508,10 @@ class SweepScheduler:
             event["trace_hash"] = record["trace_hash"]
         if record.get("poison"):
             event["poison"] = True
+        if record.get("timing_fallback"):
+            # the batched timing pass failed and the chunk was re-timed
+            # per scenario: right answer, wrong path — say so in the stream
+            event["timing_fallback"] = True
         job.emit(event)
         self.metrics.inc("rows_streamed")
         self.metrics.observe("row_s", time.time() - job.t_submit)
@@ -672,6 +679,8 @@ class SweepScheduler:
             for cache_name, delta in out["hostcache"].items():
                 for k, v in delta.items():
                     self.metrics.inc(f"worker_hostcache_{cache_name}_{k}", v)
+            for k, v in (out.get("device") or {}).items():
+                self.metrics.inc(f"worker_device_{k}", v)
             self.metrics.observe("execute_s", time.time() - t0)
             if len(records) != len(chunk_hashes):
                 lost = (f"chunk returned {len(records)} records for "
@@ -702,6 +711,8 @@ class SweepScheduler:
             else:
                 for h, rec in zip(chunk_hashes, records):
                     if self._record_valid(rec):
+                        if rec.get("timing_fallback"):
+                            self.metrics.inc("timing_fallbacks")
                         self._complete_entry(h, rec)
                     else:
                         self.metrics.inc("corrupt_records")

@@ -6,7 +6,8 @@ the process's warm state — the ``hostcache`` artifact/semantics caches,
 the runner's graph memo, and jitted timing kernels.  ``init_worker`` runs
 once per process and resizes the host caches for that lifetime;
 ``run_chunk`` executes one scenario chunk and reports the host-cache
-hit/miss delta it produced, so the server can aggregate worker warmth in
+hit/miss delta and the device-work delta (dispatches, XLA compiles) it
+produced, so the server can aggregate worker warmth and device work in
 ``/stats``.
 """
 from __future__ import annotations
@@ -19,18 +20,50 @@ from repro.sweep.spec import Scenario
 ARTIFACTS_CAPACITY = 64
 SEMANTICS_CAPACITY = 16
 
+# XLA programs this process compiled or loaded from the persistent
+# compile cache (``compile_cache_hits`` of them loaded), and the seconds
+# both took
+_COMPILES = dict(compiles=0, compile_s=0.0, compile_cache_hits=0)
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    if event == "/jax/core/compile/backend_compile_duration":
+        _COMPILES["compiles"] += 1
+        _COMPILES["compile_s"] += duration
+    elif event == "/jax/compilation_cache/cache_retrieval_time_sec":
+        _COMPILES["compile_cache_hits"] += 1
+
+
+def device_counters() -> dict:
+    """:func:`repro.core.engine.dispatch_stats` plus this process's XLA
+    program count, seconds and persistent-cache hits."""
+    from repro.core.engine import dispatch_stats
+
+    return {**dispatch_stats(), **_COMPILES}
+
 
 def init_worker(artifacts_capacity: int = ARTIFACTS_CAPACITY,
-                semantics_capacity: int = SEMANTICS_CAPACITY) -> None:
-    """Per-process warm-up: resize host caches, pre-import the hot path so
-    the first job does not pay import latency inside its first chunk."""
+                semantics_capacity: int = SEMANTICS_CAPACITY) -> dict:
+    """Per-process warm-up: point the compile cache at its directory,
+    resize host caches, pre-import the hot path so the first job does not
+    pay import latency inside its first chunk, and open the device.
+    Returns the seat's :func:`repro.runtime.device_info`, which the pool
+    reports in ``/stats``; a seat asked for the TPU (``JAX_PLATFORMS=tpu``)
+    that cannot open it raises here and never takes a chunk."""
+    import jax
+
+    from repro import runtime
     from repro.core import hostcache
 
+    runtime.enable_compile_cache()
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
     hostcache.configure(artifacts_capacity=artifacts_capacity,
                         semantics_capacity=semantics_capacity)
     import repro.core.accelerators  # noqa: F401  (registers the models)
     import repro.core.engine  # noqa: F401
     import repro.core.semexec  # noqa: F401  (device semantic-execution path)
+
+    return runtime.device_info()  # raises if JAX_PLATFORMS names an absent TPU
 
 
 def run_chunk(
@@ -40,9 +73,10 @@ def run_chunk(
     with_trace_hash: bool,
     inject=None,
 ) -> dict:
-    """Execute one chunk; returns ``{"records": [...], "hostcache": delta}``
-    where the delta is this chunk's hit/miss contribution (cumulative
-    worker counters would double-count across chunks).
+    """Execute one chunk; returns ``{"records": [...], "hostcache": delta,
+    "device": delta}`` where the deltas are this chunk's host-cache
+    hit/miss and :func:`device_counters` contributions (cumulative worker
+    counters would double-count across chunks).
 
     ``inject`` is an optional :class:`repro.distributed.faults.FaultAction`
     resolved by the scheduler at dispatch time: pre-work faults (crash /
@@ -55,17 +89,18 @@ def run_chunk(
         from repro.distributed import faults
 
         faults.apply_pre(inject)
-    before = stats_all()
+    before, d_before = stats_all(), device_counters()
     records = execute_chunk(scenarios, mode=mode, policy=policy,
                             with_trace_hash=with_trace_hash)
     if inject is not None and inject.kind == "corrupt":
         from repro.distributed import faults
 
         records = faults.corrupt_records(records)
-    after = stats_all()
+    after, d_after = stats_all(), device_counters()
     delta = {
         cache: {k: after[cache][k] - before[cache][k]
                 for k in ("hits", "misses")}
         for cache in after
     }
-    return dict(records=records, hostcache=delta)
+    device = {k: d_after[k] - d_before[k] for k in d_after}
+    return dict(records=records, hostcache=delta, device=device)

@@ -151,7 +151,8 @@ def main(argv: list[str] | None = None) -> int:
     add_spec_args(ap)
     add_policy_args(ap)
     ap.add_argument("--workers", type=int, default=0,
-                    help="process-pool size; <=1 runs serially")
+                    help="process-pool size; <=1 runs serially (on a TPU "
+                         "host, JAX_PLATFORMS=tpu refuses more than 1)")
     ap.add_argument("--mode", default="scenario", choices=("scenario", "batch"),
                     help="batch: group all DRAM traces of a worker's chunk "
                          "into a few batched device dispatches")
@@ -178,14 +179,18 @@ def main(argv: list[str] | None = None) -> int:
         print(f"{len(scenarios)} scenarios, {len(skipped)} skipped")
         return 0
 
-    result = run_sweep(
-        spec,
-        cache_dir=args.cache or None,
-        workers=args.workers,
-        mode=args.mode,
-        policy=policy,
-        progress=lambda msg: print(msg, flush=True),
-    )
+    try:
+        result = run_sweep(
+            spec,
+            cache_dir=args.cache or None,
+            workers=args.workers,
+            mode=args.mode,
+            policy=policy,
+            progress=lambda msg: print(msg, flush=True),
+        )
+    except ValueError as e:  # e.g. two device seats on one chip
+        print(f"error: {e}", file=sys.stderr)
+        return 2
     rows = result_rows(result, with_status=True)
     if rows:
         csv_path = f"{args.out}/{spec.name}.csv"

@@ -44,6 +44,7 @@ from repro.core.metrics import SimReport
 from repro.graph.generators import GraphSpec
 from repro.graph.problems import PROBLEMS
 from repro.graph.structure import Graph
+from repro.runtime import check_device_seats, enable_compile_cache
 from repro.sweep.cache import ResultCache, scenario_hash
 from repro.sweep.spec import Scenario, Skipped, SweepSpec
 
@@ -502,6 +503,8 @@ def run_sweep(
     serve scheduler uses (:class:`ExecutionPolicy`)."""
     if mode not in ("scenario", "batch"):
         raise ValueError(f"unknown mode {mode!r} (use scenario|batch)")
+    check_device_seats(workers)
+    enable_compile_cache()
     say = progress or (lambda msg: None)
     scenarios, skipped = spec.expand()
     for sk in skipped:
@@ -537,7 +540,9 @@ def run_sweep(
         chunks = _chunk_evenly(unique_pending, workers if workers > 1 else 1)
         if workers > 1 and len(chunks) > 1:
             ctx = multiprocessing.get_context("spawn")
-            with ProcessPoolExecutor(max_workers=workers, mp_context=ctx) as pool:
+            with ProcessPoolExecutor(
+                    max_workers=workers, mp_context=ctx,
+                    initializer=enable_compile_cache) as pool:
                 futures = {
                     pool.submit(execute_chunk,
                                 [scenarios[pending_by_hash[h][0]] for h in chunk],
@@ -569,7 +574,8 @@ def run_sweep(
                 f"(artifacts+semantics)")
     elif workers > 1 and len(unique_pending) > 1:
         ctx = multiprocessing.get_context("spawn")
-        with ProcessPoolExecutor(max_workers=workers, mp_context=ctx) as pool:
+        with ProcessPoolExecutor(max_workers=workers, mp_context=ctx,
+                                 initializer=enable_compile_cache) as pool:
             futures = {
                 pool.submit(execute_scenario_policied,
                             scenarios[pending_by_hash[h][0]], policy): h

@@ -46,6 +46,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.runtime import enable_compile_cache
 from repro.sweep.cache import ResultCache, scenario_hash
 from repro.sweep.results import scenario_row
 from repro.sweep.runner import ExecutionPolicy, execute_chunk, plan_scenarios
@@ -161,6 +162,7 @@ class RunnerExecutor:
     def __init__(self, cache: ResultCache, mode: str = "batch",
                  policy: ExecutionPolicy | None = None,
                  with_trace_hash: bool = False):
+        enable_compile_cache()  # executes in this process
         self.cache = cache
         self.mode = mode
         self.policy = policy

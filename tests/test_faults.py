@@ -194,6 +194,20 @@ def test_pool_retires_slot_and_breaks_after_respawn_budget():
         pool.shutdown(wait=False, cancel_pending=True)
 
 
+def test_pool_reports_seat_device_from_initializer():
+    """A serve seat opens its device at start-up and the pool reports it:
+    a seat that came up on the wrong platform is visible in /stats."""
+    from repro.serve.worker import init_worker
+
+    pool = make_pool(initializer=init_worker)
+    try:
+        assert pool.submit(probe, None, 1).result(timeout=120)["value"] == 1
+        assert pool.stats()["seats"] == [
+            dict(platform="cpu", kind="cpu", count=1)]
+    finally:
+        pool.shutdown(wait=False, cancel_pending=True)
+
+
 def test_pool_shutdown_bounded_with_hung_worker():
     pool = make_pool(task_deadline_s=1.0)
     pool.submit(probe, None, 0).result(timeout=60)  # worker is ready

@@ -312,3 +312,18 @@ def test_spmv_edges_padding_and_isolated():
                                jnp.asarray(wp), jnp.asarray(x), n))
     np.testing.assert_array_equal(y, yp)
     assert np.all(y[n // 2:] == 0.0)  # no in-edges -> empty sum
+
+
+@pytest.mark.parametrize("use_pallas,interpret,want", [
+    (None, None, (True, False)),
+    (True, None, (True, False)),
+    (False, None, (False, False)),
+    (None, False, (True, False)),
+    (None, True, (True, True)),  # only an explicit request interprets
+])
+def test_resolve_pallas_never_interprets_on_tpu(monkeypatch, use_pallas,
+                                                interpret, want):
+    from repro.kernels import _platform
+
+    monkeypatch.setattr(_platform, "on_tpu", lambda: True)
+    assert _platform.resolve_pallas(use_pallas, interpret) == want

@@ -297,6 +297,43 @@ def test_scheduler_errors_not_cached(tmp_path):
         sched.close()
 
 
+def test_scheduler_counts_seat_device_work(tmp_path):
+    """Seats report each chunk's device dispatches and compiles; the
+    scheduler sums them into worker_device_* counters."""
+    sched = scheduler(tmp_path, GatedPool(), chunk_size=2)
+    try:
+        job = sched.submit(tiny_spec(accels=("accugraph", "hitgraph")))
+        assert [e["status"] for e in collect_events(job)[1:-1]] == ["ok"] * 2
+        c = sched.stats()["counters"]
+        assert c["worker_device_dispatches"] >= 1
+        assert c["worker_device_traces"] >= 2
+        assert c["worker_device_requests"] > 0
+        assert c["worker_device_host_traces"] == 0  # all under SCAN_CUTOFF
+        assert c.get("timing_fallbacks", 0) == 0
+    finally:
+        sched.close()
+
+
+def test_timing_fallback_is_flagged_in_the_stream(tmp_path, monkeypatch):
+    """A failed batched timing pass re-times per scenario: the rows stay
+    right, and the stream and the counters say which path ran."""
+    from repro.core import engine
+
+    def broken(items):
+        raise RuntimeError("batched dispatch refused")
+
+    monkeypatch.setattr(engine, "simulate_many", broken)
+    sched = scheduler(tmp_path, GatedPool(), chunk_size=2)
+    try:
+        job = sched.submit(tiny_spec(accels=("accugraph", "hitgraph")))
+        rows = collect_events(job)[1:-1]
+        assert [e["status"] for e in rows] == ["ok", "ok"]
+        assert all(e["timing_fallback"] for e in rows)
+        assert sched.stats()["counters"]["timing_fallbacks"] == 2
+    finally:
+        sched.close()
+
+
 # ---- execution policy: timeout + bounded retry ------------------------------
 
 
