@@ -223,7 +223,10 @@ def run_full(out: str, workers: int) -> int:
         jobs_per_s=round(len(specs) / wall, 4),
         rows_per_s=round(rows_total / wall, 3),
         row_latency_s=stats["latency"].get("row_s", {}),
-        execute_latency_s=stats["latency"].get("execute_s", {}),
+        worker_stage_s={k[len("worker_stage_"):]: round(v, 6)
+                        for k, v in c.items()
+                        if k.startswith("worker_stage_")
+                        and k.endswith("_s")},
         queue_wait_s=stats["latency"].get("queue_wait_s", {}),
         collapse=dict(
             submitted=c.get("scenarios_submitted", 0),

@@ -200,5 +200,5 @@ class AccuGraph(Accelerator):
                 break
 
         if device:
-            values = np.asarray(values_dev)
+            values = semexec.to_host(values_dev)
         return values, iters, pt, stats, extras

@@ -39,6 +39,7 @@ from repro.graph.layout import (
 )
 from repro.graph.problems import Problem
 from repro.graph.structure import Graph
+from repro.spans import span, spanned
 
 INF = np.float32(np.inf)
 
@@ -287,6 +288,7 @@ class Accelerator(abc.ABC):
         through ``prepare``, which resolves ``config.semexec``."""
         ...
 
+    @spanned("prepare")
     def prepare(
         self,
         g: Graph,
@@ -315,10 +317,12 @@ class Accelerator(abc.ABC):
         if isinstance(dram, str):
             dram = dram_config(dram)
         dram = dram or dram_config(self.default_dram)
+        def prepare_graph():
+            with span("prepare_graph"):
+                return problem.prepare_graph(g)
+
         gp = ARTIFACTS.get_or_build(
-            (g.fingerprint, "prepared", problem.name),
-            lambda: problem.prepare_graph(g),
-        )
+            (g.fingerprint, "prepared", problem.name), prepare_graph)
         perm = None
         gx, root_x = gp, root
         if self.config.reorder != "identity":
@@ -334,7 +338,8 @@ class Accelerator(abc.ABC):
             init = None
             if perm is not None:
                 init = relabel_values(problem.init_values(gp, root), perm)
-            return self._execute(gx, problem, root_x, init, engine)
+            with span("semantics"):
+                return self._execute(gx, problem, root_x, init, engine)
 
         values, iters, pt, stats, extras = SEMANTICS.get_or_build(
             (gx.fingerprint, self.name, problem.name, root_x,

@@ -247,7 +247,7 @@ class ForeGraph(Accelerator):
                 break
 
         if device:
-            values = np.asarray(values_dev)
+            values = semexec.to_host(values_dev)
         if sperm is not None:
             # values are indexed by stride-renamed ids; map back to the
             # pre-stride ids (WCC labels re-canonicalised to min id)

@@ -294,5 +294,5 @@ class HitGraph(Accelerator):
                 break
 
         if device:
-            values = np.asarray(values_dev)
+            values = semexec.to_host(values_dev)
         return values, iters, pt, stats, extras

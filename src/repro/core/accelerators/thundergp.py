@@ -230,5 +230,5 @@ class ThunderGP(Accelerator):
                 break
 
         if device:
-            values = np.asarray(values_dev)
+            values = semexec.to_host(values_dev)
         return values, iters, pt, stats, extras

@@ -55,6 +55,7 @@ import numpy as np
 
 from repro.core.dram import DRAMConfig, decode_lines
 from repro.core.trace import Trace, split_round_robin
+from repro.spans import span, spanned
 
 # Version tag of the simulation semantics (accelerator models + DRAM timing
 # engines).  Bump whenever a change alters simulation *results*; the sweep
@@ -323,6 +324,7 @@ class TraceBatch:
         return int(self.bank.shape[1])
 
     @staticmethod
+    @spanned("emit")
     def from_traces(
         traces: Sequence[Trace],
         cfg: DRAMConfig,
@@ -463,6 +465,7 @@ def _classify_fast_batch(bank: np.ndarray, row: np.ndarray, valid: np.ndarray,
     return cls
 
 
+@spanned("fast")
 def _simulate_fast_batch(traces: list[Trace], cfg: DRAMConfig) -> list[TimingReport]:
     """Batched analytic engine: one vectorised pass over padded [B, L]
     arrays.  All arithmetic is integer-exact (cycle counts summed in
@@ -577,16 +580,18 @@ def simulate_batch(
     for L, idxs in sorted(by_bucket.items()):
         for chunk in _chunk(idxs, max(1, MAX_BATCH_ELEMS // L)):
             batch = TraceBatch.from_traces([traces[i] for i in chunk], cfg)
-            cycles, hits, misses, conflicts = _scan_engine_batch(
-                jnp.asarray(batch.bank), jnp.asarray(batch.row), cfg.nbanks,
-                t["tCL"], t["tRCD"], t["tRP"], t["tRC"], t["tBL"],
-                lookahead=16 * t["tBL"], page_open=cfg.page_open,
-            )
-            _record_dispatch(len(chunk), int(batch.lengths.sum()))
-            cycles, hits, misses, conflicts = (  # one host sync per dispatch
-                np.asarray(cycles), np.asarray(hits),
-                np.asarray(misses), np.asarray(conflicts),
-            )
+            with span("scan"):
+                cycles, hits, misses, conflicts = _scan_engine_batch(
+                    jnp.asarray(batch.bank), jnp.asarray(batch.row),
+                    cfg.nbanks, t["tCL"], t["tRCD"], t["tRP"], t["tRC"],
+                    t["tBL"], lookahead=16 * t["tBL"],
+                    page_open=cfg.page_open,
+                )
+                _record_dispatch(len(chunk), int(batch.lengths.sum()))
+                cycles, hits, misses, conflicts = (  # one host sync
+                    np.asarray(cycles), np.asarray(hits),
+                    np.asarray(misses), np.asarray(conflicts),
+                )
             for j, i in enumerate(chunk):
                 reports[i] = _channel_report(
                     traces[i], cfg, int(cycles[j]), int(hits[j]),
@@ -622,6 +627,7 @@ def _timing_key(cfg: DRAMConfig) -> tuple:
             t["tBL"], cfg.tCK_ns, cfg.bw_per_channel)
 
 
+@spanned("timing")
 def simulate_many(
     items: Sequence[tuple[Trace, DRAMConfig, str, int]],
 ) -> list[TimingReport]:

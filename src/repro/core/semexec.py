@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.hostcache import ARTIFACTS
+from repro.spans import span, spanned
 
 ENGINES = ("numpy", "device")
 
@@ -89,6 +90,14 @@ def resolve_engine(accel: str, problem_name: str, requested: str) -> str:
             f"semexec: no device formulation for {accel}/{problem_name}; "
             f"falling back to the numpy engine", UserWarning, stacklevel=2)
     return "numpy"
+
+
+@spanned("semexec_wait")
+def to_host(x) -> np.ndarray:
+    """The host copy of a device result: blocks until the jitted steps
+    that produce it have run (a ``semexec_wait`` span, like every step's
+    host sync)."""
+    return np.asarray(x)
 
 
 # ---------------------------------------------------------------------------
@@ -417,7 +426,8 @@ class HitGraphDevice:
             lay["src"], lay["delta"], lay["part"], lay["plans"],
             use_filter=self.filter_opt, use_skip=self.skip_opt,
             combine=self.combine_opt)
-        return new, np.asarray(changed), np.asarray(nupd).astype(np.int64)
+        with span("semexec_wait"):
+            return new, np.asarray(changed), np.asarray(nupd).astype(np.int64)
 
     def acc_step(self, values_dev):
         lay = self.lay
@@ -477,7 +487,8 @@ class AccuGraphDevice:
             return values_dev, np.zeros(0, dtype=bool)
         new, changed = _gs_min_step(values_dev, lay["esrc"][p],
                                     lay["ud"][p], self.delta, lay["plan"][p])
-        return new, np.asarray(changed)[: lay["u_count"][p]]
+        with span("semexec_wait"):
+            return new, np.asarray(changed)[: lay["u_count"][p]]
 
     def acc_step(self, values_dev, snapshot_dev, p: int):
         lay = self.lay
@@ -541,7 +552,8 @@ class ThunderGPDevice:
         lay = self.lay
         new, anyc = _jacobi_min_step(values_dev, lay["src"], lay["delta"],
                                      lay["plan"])
-        return new, bool(anyc)
+        with span("semexec_wait"):
+            return new, bool(anyc)
 
     def acc_step(self, values_dev):
         lay = self.lay
@@ -613,7 +625,8 @@ class ForeGraphDevice:
         lay = self.lay
         new, flags = _fg_min_step(values_dev, *lay["abc"][i], self.delta,
                                   lay["plans"][i])
-        return new, np.asarray(flags).astype(bool)
+        with span("semexec_wait"):
+            return new, np.asarray(flags).astype(bool)
 
     def acc_step(self, values_dev):
         lay = self.lay

@@ -114,8 +114,9 @@ class RemoteWorkerPool:
     """Scheduler-side half of multi-host serving.  Pool-contract compatible
     with :class:`~repro.distributed.workpool.WorkerPool`, but ``submit``
     only accepts the scheduler's one dispatch shape —
-    ``submit(run_chunk, scenarios, mode, policy, trace_hashes, inject)`` —
-    because the arguments must cross a wire, not a pickle pipe.
+    ``submit(run_chunk, scenarios, mode, policy, trace_hashes, inject,
+    chunk)``, ``chunk`` optional — because the arguments must cross a
+    wire, not a pickle pipe.
 
     ``size`` is dynamic: the total seats of currently connected hosts
     (0 until the first host registers — the scheduler reads it per
@@ -178,8 +179,9 @@ class RemoteWorkerPool:
             raise TypeError(
                 "RemoteWorkerPool only dispatches repro.serve.worker."
                 f"run_chunk chunks, not {fn!r} (arguments cross a wire)")
-        if len(args) != 5:
-            raise TypeError(f"run_chunk takes 5 arguments, got {len(args)}")
+        if len(args) not in (5, 6):
+            raise TypeError(
+                f"run_chunk takes 5 or 6 arguments, got {len(args)}")
         with self._lock:
             if self._stopping:
                 raise RuntimeError("remote worker pool is shut down")
@@ -425,7 +427,12 @@ class RemoteWorkerPool:
                         (task.future,
                          dict(records=records,
                               hostcache=body.get("hostcache") or {},
-                              device=body.get("device") or {}), False))
+                              graph_memo=body.get("graph_memo") or {},
+                              device=body.get("device") or {},
+                              stages=body.get("stages") or {},
+                              spans=body.get("spans") or [],
+                              seat=body.get("seat", h.name),
+                              chunk=body.get("dispatch")), False))
             elif isinstance(body.get("lost"), dict):
                 # the host's *local* pool lost a worker: forward the loss
                 # structure so scheduler recovery is host-transparent
@@ -725,12 +732,12 @@ class WorkerHostAgent:
                 pass
 
     def _start_chunk(self, ev: dict) -> None:
-        chunk_id, scenarios, mode, policy, trace_hashes, inject = \
+        chunk_id, scenarios, mode, policy, trace_hashes, inject, dispatch = \
             chunk_from_wire(ev)
         from repro.serve import worker as worker_mod
         try:
             fut = self.pool.submit(worker_mod.run_chunk, scenarios, mode,
-                                   policy, trace_hashes, inject)
+                                   policy, trace_hashes, inject, dispatch)
         except Exception:
             # local pool broken/draining: report the chunk as lost so the
             # scheduler re-dispatches it to another host
@@ -755,7 +762,12 @@ class WorkerHostAgent:
             body = dict(host_id=host_id, chunk=chunk_id, ok=True,
                         records=out["records"],
                         hostcache=out.get("hostcache") or {},
-                        device=out.get("device") or {})
+                        graph_memo=out.get("graph_memo") or {},
+                        device=out.get("device") or {},
+                        stages=out.get("stages") or {},
+                        spans=out.get("spans") or [],
+                        seat=out.get("seat", self.name),
+                        dispatch=out.get("chunk"))
         except CancelledError:
             return
         except WorkerLost as e:

@@ -5,10 +5,11 @@ Everything the ``/stats`` endpoint exports lives here: monotonic counters
 timeouts, and the fault-tolerance ledger — chunks_lost,
 scenarios_redispatched, scenarios_poisoned, corrupt_records,
 faults_injected, jobs_recovered...), and per-stage latency histograms
-(spec expansion, queue wait, chunk execution, submit-to-row latency).  Histograms keep exact
-count/sum/max plus a bounded reservoir of recent samples for the p50/p95
-quantiles — at serve scale the recent window is what an operator watches
-anyway.
+(spec expansion, queue wait, submit-to-row latency).  Histograms keep
+exact count/sum/max plus a bounded reservoir of recent samples for the
+p50/p95 quantiles — at serve scale the recent window is what an operator
+watches anyway.  :class:`SpanRing` keeps the worker seats' most recent
+stage spans (:mod:`repro.spans`).
 """
 from __future__ import annotations
 
@@ -80,3 +81,35 @@ class Metrics:
                 latency={k: h.snapshot()
                          for k, h in sorted(self._histograms.items())},
             )
+
+
+class SpanRing:
+    """The most recent stage spans of every chunk, ``capacity`` at most.
+
+    A chunk's rows ``[id, parent, name, t0, t1]`` (ids local to the
+    chunk) are stored as ``[id, parent, name, t0, t1, chunk, seat]`` with
+    ids renumbered unique across chunks; ``dropped`` counts the spans
+    pushed out by newer ones.  Times are the seat host's ``time.time()``:
+    spans of different hosts are on different clocks."""
+
+    def __init__(self, capacity: int):
+        self._lock = threading.Lock()
+        self._rows: deque[list] = deque(maxlen=capacity)
+        self._next_id = 0
+        self.dropped = 0
+
+    def add(self, rows: list, chunk, seat: str) -> None:
+        with self._lock:
+            base = self._next_id
+            self._next_id += len(rows)
+            self.dropped += max(0, len(self._rows) + len(rows)
+                                - self._rows.maxlen)
+            self._rows.extend(
+                [base + i, None if parent is None else base + parent, name,
+                 round(t0, 6), round(t1, 6), chunk, seat]
+                for i, parent, name, t0, t1 in rows)
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(clock="unix_s", dropped=self.dropped,
+                        spans=list(self._rows))

@@ -228,25 +228,30 @@ def action_from_wire(d: dict | None):
 
 def chunk_to_wire(chunk_id: int, scenarios, mode: str,
                   policy: ExecutionPolicy | None, trace_hashes: bool,
-                  inject=None) -> dict:
-    """One chunk-dispatch event: exactly the ``run_chunk`` argument list,
+                  inject=None, dispatch: int | None = None) -> dict:
+    """One chunk-dispatch event: exactly the ``run_chunk`` argument list
+    (``dispatch`` is the scheduler's dispatch index, its ``chunk``),
     JSON-rendered, plus the pool's chunk id for result correlation."""
     return dict(type="chunk", chunk=int(chunk_id),
                 scenarios=[scenario_to_wire(s) for s in scenarios],
                 mode=mode, policy=policy_to_wire(policy),
                 trace_hashes=bool(trace_hashes),
-                inject=action_to_wire(inject))
+                inject=action_to_wire(inject),
+                dispatch=None if dispatch is None else int(dispatch))
 
 
 def chunk_from_wire(d: dict) -> tuple:
-    """-> ``(chunk_id, scenarios, mode, policy, trace_hashes, inject)``."""
+    """-> ``(chunk_id, scenarios, mode, policy, trace_hashes, inject,
+    dispatch)``."""
     try:
+        dispatch = d.get("dispatch")
         return (int(d["chunk"]),
                 [scenario_from_wire(s) for s in d["scenarios"]],
                 d["mode"],
                 policy_from_wire(d.get("policy")),
                 bool(d.get("trace_hashes", False)),
-                action_from_wire(d.get("inject")))
+                action_from_wire(d.get("inject")),
+                None if dispatch is None else int(dispatch))
     except (TypeError, KeyError, ValueError) as e:
         raise ProtocolError(f"bad chunk message: {e}")
 

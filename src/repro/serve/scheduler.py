@@ -75,7 +75,7 @@ from repro.distributed.workpool import WorkerLost, WorkerPool
 from repro.runtime import check_device_seats
 from repro.serve import worker as worker_mod
 from repro.serve.journal import JobJournal
-from repro.serve.metrics import Metrics
+from repro.serve.metrics import Metrics, SpanRing
 from repro.sweep.cache import ResultCache, scenario_hash
 from repro.sweep.results import scenario_row
 from repro.sweep.runner import ExecutionPolicy, plan_scenarios
@@ -83,6 +83,11 @@ from repro.sweep.search.loop import SearchAborted, SearchSpec, run_search
 from repro.sweep.spec import Scenario, SweepSpec
 
 TERMINAL_EVENTS = ("done", "cancelled", "interrupted")
+
+# Stage spans kept for ``/stats``: about six minutes of one seat's
+# Graph500 BFS campaign (some 650 spans a minute on a v5e host), about
+# 330 KB of JSON when full.
+SPAN_RING = 4096
 
 
 class JobState:
@@ -215,6 +220,7 @@ class SweepScheduler:
         self.poison_threshold = max(1, poison_threshold)
         self.fault_plan = fault_plan
         self.metrics = Metrics()
+        self.spans = SpanRing(SPAN_RING)
         self.log = log or (lambda event, **kw: None)
         self.t_start = time.time()
 
@@ -650,12 +656,12 @@ class SweepScheduler:
                     keys=tuple(s.scenario_id for s in scenarios))
                 if inject is not None:
                     self.metrics.inc("faults_injected")
-            t0 = time.time()
             self.metrics.inc("chunks_dispatched")
             try:
                 fut = self.pool.submit(worker_mod.run_chunk, scenarios,
                                        self.mode, self.policy,
-                                       self.trace_hashes, inject)
+                                       self.trace_hashes, inject,
+                                       dispatch_idx)
             except Exception as e:  # broken pool must not kill the dispatcher
                 self.log("dispatch_failed", error=repr(e),
                          chunk=len(chunk_hashes))
@@ -669,9 +675,9 @@ class SweepScheduler:
                     self._wake.notify_all()
                 continue
             fut.add_done_callback(
-                lambda f, hs=chunk_hashes, t=t0: self._chunk_done(hs, t, f))
+                lambda f, hs=chunk_hashes: self._chunk_done(hs, f))
 
-    def _chunk_done(self, chunk_hashes: list[str], t0: float, fut) -> None:
+    def _chunk_done(self, chunk_hashes: list[str], fut) -> None:
         records = lost = None
         try:
             out = fut.result()
@@ -679,9 +685,16 @@ class SweepScheduler:
             for cache_name, delta in out["hostcache"].items():
                 for k, v in delta.items():
                     self.metrics.inc(f"worker_hostcache_{cache_name}_{k}", v)
+            for k, v in (out.get("graph_memo") or {}).items():
+                self.metrics.inc(f"worker_graph_memo_{k}", v)
             for k, v in (out.get("device") or {}).items():
                 self.metrics.inc(f"worker_device_{k}", v)
-            self.metrics.observe("execute_s", time.time() - t0)
+            for name, tot in (out.get("stages") or {}).items():
+                self.metrics.inc(f"worker_stage_{name}_s", tot["s"])
+                self.metrics.inc(f"worker_stage_{name}_n", tot["n"])
+            if out.get("spans"):
+                self.spans.add(out["spans"], out.get("chunk"),
+                               out.get("seat", ""))
             if len(records) != len(chunk_hashes):
                 lost = (f"chunk returned {len(records)} records for "
                         f"{len(chunk_hashes)} scenarios")
@@ -846,4 +859,5 @@ class SweepScheduler:
             workers=pool_stats,
             counters=counters,
             latency=snap["latency"],
+            spans=self.spans.snapshot(),
         )

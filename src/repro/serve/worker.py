@@ -6,13 +6,18 @@ the process's warm state — the ``hostcache`` artifact/semantics caches,
 the runner's graph memo, and jitted timing kernels.  ``init_worker`` runs
 once per process and resizes the host caches for that lifetime;
 ``run_chunk`` executes one scenario chunk and reports the host-cache
-hit/miss delta and the device-work delta (dispatches, XLA compiles) it
-produced, so the server can aggregate worker warmth and device work in
-``/stats``.
+hit/miss delta, the graph-memo delta, the device-work delta (dispatches,
+XLA compiles) and the stage spans (:mod:`repro.spans`) it produced, so
+the server can aggregate worker warmth, device work and where the seat's
+host time goes in ``/stats``.
 """
 from __future__ import annotations
 
-from repro.sweep.runner import ExecutionPolicy, execute_chunk
+import os
+import socket
+
+from repro import spans
+from repro.sweep.runner import ExecutionPolicy, execute_chunk, graph_memo_stats
 from repro.sweep.spec import Scenario
 
 # Long-lived workers see many jobs over many graphs; hold more offline
@@ -72,11 +77,18 @@ def run_chunk(
     policy: ExecutionPolicy | None,
     with_trace_hash: bool,
     inject=None,
+    chunk: int | None = None,
 ) -> dict:
     """Execute one chunk; returns ``{"records": [...], "hostcache": delta,
-    "device": delta}`` where the deltas are this chunk's host-cache
-    hit/miss and :func:`device_counters` contributions (cumulative worker
-    counters would double-count across chunks).
+    "graph_memo": delta, "device": delta, "stages": totals, "spans":
+    rows, "seat": label}``.  The deltas are this chunk's host-cache
+    hit/miss, graph-memo hit/miss and :func:`device_counters`
+    contributions (cumulative worker counters would double-count across
+    chunks); ``stages`` is :func:`repro.spans.stage_totals` of the chunk's
+    ``spans`` (rows ``[id, parent, name, t0, t1]`` on this host's clock,
+    the root a ``chunk`` span), and ``seat`` names this process
+    (``host:pid``).  ``chunk`` is the scheduler's dispatch index, echoed
+    back for the spans it labels.
 
     ``inject`` is an optional :class:`repro.distributed.faults.FaultAction`
     resolved by the scheduler at dispatch time: pre-work faults (crash /
@@ -90,17 +102,23 @@ def run_chunk(
 
         faults.apply_pre(inject)
     before, d_before = stats_all(), device_counters()
-    records = execute_chunk(scenarios, mode=mode, policy=policy,
-                            with_trace_hash=with_trace_hash)
+    g_before = graph_memo_stats()
+    with spans.collect() as rows, spans.span("chunk"):
+        records = execute_chunk(scenarios, mode=mode, policy=policy,
+                                with_trace_hash=with_trace_hash)
     if inject is not None and inject.kind == "corrupt":
         from repro.distributed import faults
 
         records = faults.corrupt_records(records)
     after, d_after = stats_all(), device_counters()
+    g_after = graph_memo_stats()
     delta = {
         cache: {k: after[cache][k] - before[cache][k]
                 for k in ("hits", "misses")}
         for cache in after
     }
     device = {k: d_after[k] - d_before[k] for k in d_after}
-    return dict(records=records, hostcache=delta, device=device)
+    return dict(records=records, hostcache=delta,
+                graph_memo={k: g_after[k] - g_before[k] for k in g_after},
+                device=device, stages=spans.stage_totals(rows), spans=rows,
+                seat=f"{socket.gethostname()}:{os.getpid()}", chunk=chunk)

@@ -45,6 +45,7 @@ from repro.graph.generators import GraphSpec
 from repro.graph.problems import PROBLEMS
 from repro.graph.structure import Graph
 from repro.runtime import check_device_seats, enable_compile_cache
+from repro.spans import span
 from repro.sweep.cache import ResultCache, scenario_hash
 from repro.sweep.spec import Scenario, Skipped, SweepSpec
 
@@ -57,12 +58,23 @@ from repro.sweep.spec import Scenario, Skipped, SweepSpec
 # fingerprints + partitioning/config params), so scenarios differing only
 # in the accelerator or DRAM axes skip the offline preprocessing.
 _GRAPHS: dict[GraphSpec, Graph] = {}
+# lookups of the memo since the process started; serve seats report them
+# per chunk
+_GRAPH_MEMO = dict(hits=0, misses=0)
+
+
+def graph_memo_stats() -> dict:
+    return dict(_GRAPH_MEMO)
 
 
 def _graph(spec: GraphSpec) -> Graph:
     g = _GRAPHS.get(spec)
     if g is None:
-        g = _GRAPHS[spec] = spec.build()
+        _GRAPH_MEMO["misses"] += 1
+        with span("graph"):
+            g = _GRAPHS[spec] = spec.build()
+    else:
+        _GRAPH_MEMO["hits"] += 1
     return g
 
 
@@ -108,11 +120,13 @@ def execute_scenario(scenario: Scenario, with_trace_hash: bool = False) -> dict:
         accel = ACCELERATORS[scenario.accelerator](scenario.config)
         pending = accel.prepare(g, PROBLEMS[scenario.problem],
                                 root=scenario.root, dram=scenario.dram)
-        rep = pending.finalize()
-        rec = _ok_record(rep, _graph_stats(g), time.time() - t0)
+        with span("finalize"):
+            rep = pending.finalize()
+            rec = _ok_record(rep, _graph_stats(g), time.time() - t0)
         if with_trace_hash:
             from repro.core.trace import trace_stream_hash
-            rec["trace_hash"] = trace_stream_hash(pending.traces())[:16]
+            with span("trace_hash"):
+                rec["trace_hash"] = trace_stream_hash(pending.traces())[:16]
         return rec
     except Exception:
         return _error_record(t0)
@@ -297,7 +311,8 @@ def execute_scenarios_batch(scenarios: list[Scenario],
                                     dram=s.dram)
             if with_trace_hash:
                 from repro.core.trace import trace_stream_hash
-                hashes[i] = trace_stream_hash(pending.traces())[:16]
+                with span("trace_hash"):
+                    hashes[i] = trace_stream_hash(pending.traces())[:16]
             # only the scalar stats are kept: the chunk must not pin every
             # graph's edge arrays until the last finalize
             prepared[i] = (pending, pending.traces(), _graph_stats(g),
@@ -330,14 +345,18 @@ def execute_scenarios_batch(scenarios: list[Scenario],
         pending, traces, gstats, prep_wall = p
         t_fin = time.time()
         try:
-            if reports is None:
-                rep = pending.finalize()
-            else:
-                rep = pending.finalize(reports[offset : offset + len(traces)])
-            # wall_s = own prepare + amortised share of the shared timing
-            # pass + own finalize (comparable to scenario-mode wall_s)
-            wall = prep_wall + sim_share * len(traces) + (time.time() - t_fin)
-            records[i] = _ok_record(rep, gstats, wall)
+            with span("finalize"):
+                if reports is None:
+                    rep = pending.finalize()
+                else:
+                    rep = pending.finalize(
+                        reports[offset : offset + len(traces)])
+                # wall_s = own prepare + amortised share of the shared
+                # timing pass + own finalize (comparable to scenario-mode
+                # wall_s)
+                wall = (prep_wall + sim_share * len(traces)
+                        + (time.time() - t_fin))
+                records[i] = _ok_record(rep, gstats, wall)
             if hashes[i] is not None:
                 records[i]["trace_hash"] = hashes[i]
             if timing_fallback is not None:

@@ -104,9 +104,10 @@ def test_policy_wire_roundtrip_carries_fault_plan():
 def test_chunk_wire_roundtrip():
     scenarios, _ = tiny_spec().expand()
     ev = json.loads(json.dumps(chunk_to_wire(
-        7, scenarios, "batch", ExecutionPolicy(retries=1), True, None)))
-    chunk_id, back, mode, policy, hashes, inject = chunk_from_wire(ev)
-    assert chunk_id == 7 and back == list(scenarios)
+        7, scenarios, "batch", ExecutionPolicy(retries=1), True, None, 41)))
+    chunk_id, back, mode, policy, hashes, inject, dispatch = \
+        chunk_from_wire(ev)
+    assert chunk_id == 7 and dispatch == 41 and back == list(scenarios)
     assert mode == "batch" and policy.retries == 1
     assert hashes is True and inject is None
     with pytest.raises(ProtocolError):
