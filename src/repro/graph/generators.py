@@ -194,11 +194,16 @@ class GraphSpec:
     root: int  # BFS/SSSP root (paper specifies roots per graph)
 
     def canonical(self) -> dict:
-        """Canonical identity of the generated graph: every field that
-        determines the edge list, in declaration order.  Generators are
-        seeded, so equal ``canonical()`` dicts mean byte-identical graphs —
-        this is the graph component of the sweep cache key."""
+        """The scenario's graph identity for the sweep result cache: every
+        field in declaration order, ``root`` included, since results depend
+        on the root.  This is the graph component of the cache key."""
         return dataclasses.asdict(self)
+
+    def build_key(self) -> tuple:
+        """The fields :meth:`build` reads: every field but ``root``.
+        Generators are seeded, so equal keys mean byte-identical graphs."""
+        return tuple((f.name, getattr(self, f.name))
+                     for f in dataclasses.fields(self) if f.name != "root")
 
     def build(self) -> Graph:
         if self.kind == "community":

@@ -49,15 +49,17 @@ from repro.spans import span
 from repro.sweep.cache import ResultCache, scenario_hash
 from repro.sweep.spec import Scenario, Skipped, SweepSpec
 
-# Per-process graph memo: workers (and serial runs) build each GraphSpec
-# once even when it appears in many scenarios (GraphSpec is frozen and
-# seeded, so the spec IS the graph's canonical identity).  Downstream
+# Per-process graph memo: workers (and serial runs) build each graph once
+# even when it appears in many scenarios.  It is keyed on
+# ``GraphSpec.build_key()``, the fields the seeded build reads (all but
+# the BFS/SSSP root), so specs that differ only in the root share one
+# ``Graph``; nothing downstream mutates a ``Graph``.  Downstream
 # host artifacts — prepared graphs, partition indices, per-partition
 # routing, semantic executions — are likewise reused across the worker's
 # scenarios through ``repro.core.hostcache`` (keyed on graph content
 # fingerprints + partitioning/config params), so scenarios differing only
 # in the accelerator or DRAM axes skip the offline preprocessing.
-_GRAPHS: dict[GraphSpec, Graph] = {}
+_GRAPHS: dict[tuple, Graph] = {}
 # lookups of the memo since the process started; serve seats report them
 # per chunk
 _GRAPH_MEMO = dict(hits=0, misses=0)
@@ -68,11 +70,12 @@ def graph_memo_stats() -> dict:
 
 
 def _graph(spec: GraphSpec) -> Graph:
-    g = _GRAPHS.get(spec)
+    key = spec.build_key()
+    g = _GRAPHS.get(key)
     if g is None:
         _GRAPH_MEMO["misses"] += 1
         with span("graph"):
-            g = _GRAPHS[spec] = spec.build()
+            g = _GRAPHS[key] = spec.build()
     else:
         _GRAPH_MEMO["hits"] += 1
     return g

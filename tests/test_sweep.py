@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.configs.graphsim import default_config
+from repro.core import hostcache
 from repro.core.accelerators.base import run_accelerator
 from repro.core.dram import dram_config
 from repro.graph.generators import GraphSpec
@@ -20,6 +21,8 @@ from repro.sweep import (
     write_csv,
 )
 from repro.sweep import cache as cache_mod
+from repro.sweep import runner as runner_mod
+from repro.sweep.runner import graph_memo_stats
 
 TINY = GraphSpec("tiny", "uniform", 256, 1024, True, 1, 0)
 TINY2 = GraphSpec("tiny2", "uniform", 200, 800, True, 2, 0)
@@ -108,6 +111,14 @@ def test_scenario_hash_stable_and_sensitive():
     # the override label is presentation-only: not part of the identity
     labelled = dataclasses.replace(base, label="ablation-x")
     assert scenario_hash(labelled) == scenario_hash(base)
+
+
+def test_scenario_hash_differs_by_root_alone():
+    """The graph memo ignores the root; the result cache must not."""
+    base = tiny_spec().scenarios()[0]
+    rerooted = tiny_spec(graphs=(dataclasses.replace(TINY, root=5),)).scenarios()[0]
+    assert rerooted.graph.build_key() == base.graph.build_key()
+    assert scenario_hash(rerooted) != scenario_hash(base)
 
 
 def test_engine_version_invalidates_hash(monkeypatch):
@@ -232,6 +243,53 @@ def test_error_isolation_and_errors_not_cached(tmp_path):
     # errors are not cached: the broken scenario re-executes, the good one not
     again = run_sweep(spec, cache_dir=cache_dir)
     assert again.n_cached == 1 and again.n_errors == 1
+
+
+@pytest.fixture
+def cold_graphs():
+    """No graph or host artifact cached."""
+    runner_mod._GRAPHS.clear()
+    hostcache.clear_all()
+    yield
+    runner_mod._GRAPHS.clear()
+    hostcache.clear_all()
+
+
+def _record(s):
+    rec = execute_scenario(s)
+    assert rec["status"] == "ok"
+    return {k: v for k, v in rec.items() if k != "wall_s"}
+
+
+def test_graph_memo_builds_once_across_roots(cold_graphs):
+    a = tiny_spec().scenarios()[0]
+    b = tiny_spec(graphs=(dataclasses.replace(TINY, root=5),)).scenarios()[0]
+    before = graph_memo_stats()
+    shared = [_record(a), _record(b)]
+    after = graph_memo_stats()
+    assert {k: after[k] - before[k] for k in after} == dict(hits=1, misses=1)
+    assert runner_mod._graph(a.graph) is runner_mod._graph(b.graph)
+    assert shared[0]["report"] != shared[1]["report"]  # the root matters
+
+    fresh = []
+    for s in (a, b):
+        runner_mod._GRAPHS.clear()
+        hostcache.clear_all()
+        fresh.append(_record(s))
+    assert fresh == shared
+
+
+@pytest.mark.parametrize("field,value", [
+    ("name", "tiny-b"), ("kind", "rmat"), ("n", 512), ("target_m", 2048),
+    ("directed", False), ("seed", 9)])
+def test_graph_memo_misses_on_any_build_field(field, value, cold_graphs):
+    other = dataclasses.replace(TINY, **{field: value})
+    assert other.build_key() != TINY.build_key()
+    before = graph_memo_stats()
+    g, h = runner_mod._graph(TINY), runner_mod._graph(other)
+    after = graph_memo_stats()
+    assert {k: after[k] - before[k] for k in after} == dict(hits=0, misses=2)
+    assert g is not h
 
 
 def test_duplicate_scenarios_execute_once(tmp_path):
