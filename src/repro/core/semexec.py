@@ -68,6 +68,16 @@ _EDGE_BLOCK = 1024  # edge arrays pad to a multiple of this
 
 _FALLBACK_WARNED: set[tuple[str, str]] = set()
 
+# jitted step dispatches in this process; serve seats report the change
+# over each chunk beside the timing engine's dispatch counters
+_STEPS = dict(semexec_steps=0)
+
+
+def step_stats() -> dict:
+    """``semexec_steps``: the jitted per-iteration (or per-partition)
+    steps this process has dispatched."""
+    return dict(_STEPS)
+
 
 def validate_engine(engine: str) -> None:
     if engine not in ENGINES:
@@ -421,6 +431,7 @@ class HitGraphDevice:
 
     def min_step(self, values_dev, active: np.ndarray, proc: np.ndarray):
         lay = self.lay
+        _STEPS["semexec_steps"] += 1
         new, changed, nupd = _hitgraph_min_step(
             values_dev, jnp.asarray(active), jnp.asarray(proc),
             lay["src"], lay["delta"], lay["part"], lay["plans"],
@@ -431,6 +442,7 @@ class HitGraphDevice:
 
     def acc_step(self, values_dev):
         lay = self.lay
+        _STEPS["semexec_steps"] += 1
         return _acc_step(values_dev, lay["src"], lay["w"], self.base,
                          self.scale, lay["plan"])
 
@@ -485,6 +497,7 @@ class AccuGraphDevice:
         lay = self.lay
         if lay["u_count"][p] == 0:
             return values_dev, np.zeros(0, dtype=bool)
+        _STEPS["semexec_steps"] += 1
         new, changed = _gs_min_step(values_dev, lay["esrc"][p],
                                     lay["ud"][p], self.delta, lay["plan"][p])
         with span("semexec_wait"):
@@ -494,6 +507,7 @@ class AccuGraphDevice:
         lay = self.lay
         if lay["u_count"][p] == 0:
             return values_dev
+        _STEPS["semexec_steps"] += 1
         return _gs_acc_step(values_dev, snapshot_dev, lay["esrc"][p],
                             lay["ud"][p], lay["ew"][p], self.scale,
                             lay["plan"][p])
@@ -550,6 +564,7 @@ class ThunderGPDevice:
 
     def min_step(self, values_dev):
         lay = self.lay
+        _STEPS["semexec_steps"] += 1
         new, anyc = _jacobi_min_step(values_dev, lay["src"], lay["delta"],
                                      lay["plan"])
         with span("semexec_wait"):
@@ -557,6 +572,7 @@ class ThunderGPDevice:
 
     def acc_step(self, values_dev):
         lay = self.lay
+        _STEPS["semexec_steps"] += 1
         return _acc_step(values_dev, lay["src"], lay["w"], self.base,
                          self.scale, lay["plan"])
 
@@ -623,6 +639,7 @@ class ForeGraphDevice:
 
     def min_step(self, values_dev, i: int):
         lay = self.lay
+        _STEPS["semexec_steps"] += 1
         new, flags = _fg_min_step(values_dev, *lay["abc"][i], self.delta,
                                   lay["plans"][i])
         with span("semexec_wait"):
@@ -630,5 +647,6 @@ class ForeGraphDevice:
 
     def acc_step(self, values_dev):
         lay = self.lay
+        _STEPS["semexec_steps"] += 1
         return _acc_step(values_dev, lay["src"], lay["w"], self.base,
                          self.scale, lay["plan"])

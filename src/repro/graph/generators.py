@@ -18,6 +18,9 @@ import numpy as np
 
 from repro.graph.structure import Graph, from_edges
 
+# Key of the edge-weight stream, beside the graph's seed: Graph500 kernel 3
+WEIGHT_STREAM = 3
+
 
 def rmat(
     scale: int,
@@ -31,7 +34,12 @@ def rmat(
 ) -> Graph:
     """Graph500-style R-MAT generator (Kronecker).
 
-    n = 2**scale vertices, m = edge_factor * n edges (before dedup).
+    n = 2**scale vertices, m = edge_factor * n edges (before dedup).  The
+    undirected graph is Graph500's: every generated edge also carries a
+    float32 weight uniform in [0, 1), drawn from a stream of its own
+    (``[seed, WEIGHT_STREAM]``), so the edge list is the same with or
+    without them.  Parallel edges keep their least weight
+    (:func:`~repro.graph.structure.from_edges`).
     """
     n = 1 << scale
     m = edge_factor * n
@@ -52,7 +60,12 @@ def rmat(
     # Permute vertex labels so degree is not correlated with id.
     perm = rng.permutation(n)
     edges = np.stack([perm[src], perm[dst]], axis=1)
-    return from_edges(n, edges, directed=directed, name=name or f"rmat{scale}")
+    weights = None
+    if not directed:
+        weights = np.random.default_rng([seed, WEIGHT_STREAM]).random(
+            m, dtype=np.float32)
+    return from_edges(n, edges, directed=directed, name=name or f"rmat{scale}",
+                      weights=weights)
 
 
 def uniform_random(n: int, m: int, seed: int = 2, name: str = "uniform",

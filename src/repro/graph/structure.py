@@ -122,7 +122,9 @@ class Graph:
         return np.argsort(self.dst, kind="stable")
 
     def with_weights(self, rng: np.random.Generator | None = None) -> "Graph":
-        """Attach uniform-random integer weights in [1, 64) (paper: 32-bit)."""
+        """Attach uniform-random integer weights in [1, 64) (paper: 32-bit)
+        to a graph that has none; a graph with its own weights (Graph500's,
+        on the undirected Kronecker graph) keeps them."""
         if self.weights is not None:
             return self
         rng = rng or np.random.default_rng(7)
@@ -152,7 +154,10 @@ def from_edges(
     """Build a Graph from an (m, 2) edge array.
 
     Undirected inputs are symmetrised (both directions materialised).
-    Self-loops are removed; duplicate edges are removed when ``dedup``.
+    Self-loops are removed; duplicate edges are removed when ``dedup``: a
+    directed graph keeps the first occurrence's weight, an undirected one
+    the least weight over the pair's parallel edges, so both arcs of a
+    pair carry the same weight (the shortest-path meaning of multi-edges).
     """
     edges = np.asarray(edges, dtype=np.int64)
     if edges.size == 0:
@@ -168,10 +173,17 @@ def from_edges(
             weights = np.concatenate([weights, weights])
     if dedup:
         key = src.astype(np.int64) * n + dst
-        _, idx = np.unique(key, return_index=True)
-        src, dst = src[idx], dst[idx]
-        if weights is not None:
+        # each key's first occurrence, in key order
+        order = np.argsort(key, kind="stable")
+        first = np.ones(len(key), dtype=bool)
+        first[1:] = key[order][1:] != key[order][:-1]
+        idx = order[first]
+        if weights is not None and not directed and len(idx):
+            weights = np.minimum.reduceat(weights[order],
+                                          np.flatnonzero(first))
+        elif weights is not None:
             weights = weights[idx]
+        src, dst = src[idx], dst[idx]
     return Graph(
         n=n,
         src=src.astype(np.int32),

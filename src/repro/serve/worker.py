@@ -40,11 +40,13 @@ def _on_duration(event: str, duration: float, **kwargs) -> None:
 
 
 def device_counters() -> dict:
-    """:func:`repro.core.engine.dispatch_stats` plus this process's XLA
-    program count, seconds and persistent-cache hits."""
+    """:func:`repro.core.engine.dispatch_stats`, the semantic engine's
+    :func:`repro.core.semexec.step_stats`, and this process's XLA program
+    count, seconds and persistent-cache hits."""
     from repro.core.engine import dispatch_stats
+    from repro.core.semexec import step_stats
 
-    return {**dispatch_stats(), **_COMPILES}
+    return {**dispatch_stats(), **step_stats(), **_COMPILES}
 
 
 def init_worker(artifacts_capacity: int = ARTIFACTS_CAPACITY,

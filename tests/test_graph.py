@@ -54,6 +54,80 @@ def test_road_graph_properties():
     assert g.avg_degree < 6
 
 
+def test_undirected_kronecker_edges_unchanged_by_weights():
+    """The undirected Kronecker graph carries Graph500's weights from a
+    stream of their own: its edge list is the one it had without them."""
+    import hashlib
+
+    g = rmat(10, edge_factor=16, seed=16, directed=False)
+    assert g.weighted
+    h = hashlib.sha256(g.src.tobytes() + g.dst.tobytes()).hexdigest()
+    assert h == ("72569011fa1915772ec00f355e0b7c32"
+                 "95f8f910c68f1e40b236cf565e7ed369")
+    plain = from_edges(g.n, np.stack([g.src, g.dst], axis=1), directed=False)
+    np.testing.assert_array_equal(plain.src, g.src)
+    np.testing.assert_array_equal(plain.dst, g.dst)
+
+
+def test_undirected_kronecker_weights_are_graph500s():
+    g = rmat(10, edge_factor=16, seed=16, directed=False)
+    w = g.weights
+    assert w.dtype == np.float32 and w.shape == g.src.shape
+    assert w.min() >= 0.0 and w.max() < 1.0
+    # no subnormal weight (the TPU flushes them to zero)
+    assert np.all((w == 0) | (w >= np.finfo(np.float32).tiny))
+    # both arcs of a pair carry one weight
+    fwd = dict(zip(zip(g.src.tolist(), g.dst.tolist()), w.tolist()))
+    assert all(fwd[(d, s)] == x for (s, d), x in fwd.items())
+    # a stream of its own, keyed on the graph's seed
+    other = rmat(10, edge_factor=16, seed=17, directed=False)
+    assert not np.array_equal(other.weights[:100], w[:100])
+
+
+def test_weighted_problems_use_the_graphs_own_weights():
+    from repro.graph.problems import SSSP
+
+    g = rmat(8, edge_factor=16, seed=16, directed=False)
+    assert SSSP.prepare_graph(g).weights is g.weights
+    # a graph without weights still gets the integer weights in [1, 64)
+    d = SSSP.prepare_graph(rmat(8, edge_factor=16, seed=16))
+    assert d.weights.min() >= 1 and np.all(d.weights == np.round(d.weights))
+
+
+def test_from_edges_undirected_keeps_least_weight():
+    edges = np.array([[0, 1], [1, 0], [0, 1], [2, 3], [3, 3]])
+    w = np.array([0.5, 0.25, 0.75, 0.125, 0.0], dtype=np.float32)
+    g = from_edges(4, edges, directed=False, weights=w)
+    got = dict(zip(zip(g.src.tolist(), g.dst.tolist()), g.weights.tolist()))
+    assert got == {(0, 1): 0.25, (1, 0): 0.25, (2, 3): 0.125, (3, 2): 0.125}
+    # a directed graph keeps the first occurrence's weight, as before
+    d = from_edges(4, edges, directed=True, weights=w)
+    got = dict(zip(zip(d.src.tolist(), d.dst.tolist()), d.weights.tolist()))
+    assert got == {(0, 1): 0.5, (1, 0): 0.25, (2, 3): 0.125}
+
+
+@pytest.mark.parametrize("name,build,fingerprint", [
+    ("rmat-directed", lambda: rmat(10, edge_factor=8, seed=3),
+     "50ca02b3a8f6aef155e626b4ec7e00108d69bb517cca29a371446e4752bf5db1"),
+    ("sd", lambda: PAPER_GRAPHS["sd"].build(),
+     "36bd845794dd9b1d1c7cf009ec0ddd25756553443d7ec482cdb6f66f6e422615"),
+    ("db", lambda: PAPER_GRAPHS["db"].build(),
+     "3848b75b8f2a458cdcbe75397a2af770404813a717d086d11553db1f5b78476c"),
+    ("yt", lambda: PAPER_GRAPHS["yt"].build(),
+     "2df878e53cfbd17bc6acc8c1e2e2040317ad5929fab3a94670347556e7b9313d"),
+    ("rd", lambda: PAPER_GRAPHS["rd"].build(),
+     "a304b2a7affe4b67753afae497e45e6c64e6ffdf5f06b19053f3a01a7b6d05a1"),
+    ("r21", lambda: PAPER_GRAPHS["r21"].build(),
+     "3ca0fda59c83477769b252a452847528ab34013c2c015e1bea38cd51906586d3"),
+])
+def test_unweighted_graphs_unchanged(name, build, fingerprint):
+    """Directed R-MAT and the paper suite draw no weights: their content
+    fingerprints are the ones they had before Graph500's weights."""
+    g = build()
+    assert not g.weighted
+    assert g.fingerprint == fingerprint
+
+
 @pytest.mark.parametrize("name", ["sd", "db", "yt"])
 def test_paper_suite_builds(name):
     g = PAPER_GRAPHS[name].build()

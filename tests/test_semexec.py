@@ -43,9 +43,28 @@ def _prepare(accel: str, g, problem_name: str, engine: str):
                                             root=g.degrees_out.argmax())
 
 
-@pytest.mark.parametrize("accel,prob", COMBOS)
-def test_device_matches_numpy(accel, prob, tiny_graph):
-    g = tiny_graph.with_weights() if PROBLEMS[prob].needs_weights else tiny_graph
+@pytest.fixture(scope="module")
+def kron_graph():
+    """Graph500's undirected Kronecker graph, with its own float32 weights
+    in [0, 1) (the least over parallel edges)."""
+    return GraphSpec("kron", "rmat", 256, 4096, False, 16, 0).build()
+
+
+# every pair on the uniform graph (integer weights where weighted) and on
+# the weighted Kronecker graph; the uniform cases keep their ids
+GRAPH_COMBOS = ([pytest.param(a, p, "uniform", id=f"{a}-{p}")
+                 for a, p in COMBOS]
+                + [pytest.param(a, p, "kronecker", id=f"{a}-{p}-kronecker")
+                   for a, p in COMBOS])
+
+
+@pytest.mark.parametrize("accel,prob,graph", GRAPH_COMBOS)
+def test_device_matches_numpy(accel, prob, graph, tiny_graph, kron_graph):
+    if graph == "kronecker":
+        g = kron_graph
+    else:
+        g = (tiny_graph.with_weights() if PROBLEMS[prob].needs_weights
+             else tiny_graph)
     host = _prepare(accel, g, prob, "numpy")
     dev = _prepare(accel, g, prob, "device")
     assert host.layout["engine"] == "numpy"
@@ -139,3 +158,22 @@ def test_device_steps_reduce_through_plans(accel, monkeypatch):
         assert rep_d.timing == rep_h.timing
         assert rep_d.iterations == rep_h.iterations
         assert rep_d.runtime_s == rep_h.runtime_s
+
+
+@pytest.mark.parametrize("accel", ["hitgraph", "thundergp"])
+def test_step_counter_counts_each_device_step(accel, kron_graph):
+    """``semexec_steps`` counts the jitted steps dispatched: one an
+    iteration for HitGraph's and ThunderGP's SSSP, none on the numpy
+    engine or when the semantics cache serves the execution."""
+    from repro.core.hostcache import SEMANTICS
+
+    SEMANTICS.clear()
+    g = kron_graph
+    before = semexec.step_stats()["semexec_steps"]
+    host = _prepare(accel, g, "sssp", "numpy")
+    assert semexec.step_stats()["semexec_steps"] == before
+    dev = _prepare(accel, g, "sssp", "device")
+    assert semexec.step_stats()["semexec_steps"] == before + dev.iterations
+    assert dev.iterations == host.iterations > 1
+    _prepare(accel, g, "sssp", "device")  # a semantics-cache hit
+    assert semexec.step_stats()["semexec_steps"] == before + dev.iterations

@@ -612,3 +612,24 @@ def test_sigterm_drains_and_resume_completes(tmp_path):
     finally:
         if proc2.poll() is None:
             proc2.kill()
+
+
+def test_scheduler_counts_semexec_steps(tmp_path):
+    """Seats report the device semantic engine's jitted steps; over a job
+    of fresh SSSP executions the counter grows by the rows' iterations
+    (one step an iteration for HitGraph and ThunderGP)."""
+    kron = GraphSpec("kron-steps", "rmat", 512, 8192, False, 23, 5)
+    sched = scheduler(tmp_path, GatedPool(), chunk_size=2)
+    try:
+        job = sched.submit(tiny_spec(accels=("hitgraph", "thundergp"),
+                                     problems=("sssp",), graphs=(kron,),
+                                     engines=("device",)))
+        rows = collect_events(job)[1:-1]
+        assert [e["status"] for e in rows] == ["ok"] * 2
+        assert all(e["row"]["engine"] == "device" for e in rows)
+        steps = sum(e["row"]["iterations"] for e in rows)
+        assert steps > 2
+        c = sched.stats()["counters"]
+        assert c["worker_device_semexec_steps"] == steps
+    finally:
+        sched.close()
