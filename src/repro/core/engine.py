@@ -17,16 +17,18 @@ Two engines with identical request-level semantics:
 Both engines also exist in *batched* form: :class:`TraceBatch` packs many
 traces into padded ``[B, L]`` bank/row arrays (power-of-two bucketing on
 both axes to bound recompiles) and :func:`simulate_batch` /
-:func:`simulate_many` time a whole batch with a single vmapped device
+:func:`simulate_many` time a whole batch with a single device
 dispatch per (timing-config, length-bucket) group instead of one dispatch
 and one blocking host sync per trace.  The batched path produces
 *identical* ``TimingReport`` s to the per-trace path: padding requests are
 no-ops in the scan engine, so the bucket length never affects results.
 
-Every backend, the TPU included, times scan-routed traces with the
-vmapped ``lax.scan`` of (1).  The blocked Pallas kernel in
-``repro/kernels/dram_timing`` is not on this path: the TPU compiler refuses
-it (its ``(1, block)`` BlockSpec breaks the (8, 128) tiling rule).
+Where the batched scan is lowered for a TPU, ``_scan_engine_batch`` runs
+the lane-major Pallas kernel of ``repro/kernels/dram_timing`` instead: one
+trace a lane, the bank state on chip, every report equal to the scan's.
+Every other backend, and the per-trace oracle ``_scan_engine``, run the
+``lax.scan`` of (1).  The branch is taken at lowering
+(``lax.platform_dependent``), not by an option.
 
 Memory-controller configuration lives on :class:`repro.core.dram.DRAMConfig`
 and threads through both engines:
@@ -46,6 +48,7 @@ and threads through both engines:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import partial
 from typing import Sequence
 
@@ -104,24 +107,28 @@ def select_engine(trace_len: int, engine: str = "auto",
 # fast engine times on the host (it is numpy and launches nothing).
 # ``benchmarks/bench_engine.py`` reports these for the sequential vs
 # batched paths; serve seats report them per chunk.
-_DISPATCH = dict(dispatches=0, traces=0, requests=0, host_traces=0)
+_DISPATCH = dict(dispatches=0, traces=0, requests=0, scan_steps=0,
+                 host_traces=0)
 
 
 def reset_dispatch_stats() -> None:
-    _DISPATCH.update(dispatches=0, traces=0, requests=0, host_traces=0)
+    _DISPATCH.update(dict.fromkeys(_DISPATCH, 0))
 
 
 def dispatch_stats() -> dict:
     """Counters since the last reset: device ``dispatches``, ``traces``
-    timed through them, true (unpadded) ``requests`` simulated, and
-    ``host_traces`` timed by the host fast engine."""
+    timed through them, true (unpadded) ``requests`` simulated,
+    ``scan_steps`` (the padded length L of each dispatch, summed: the
+    sequential steps the device walked), and ``host_traces`` timed by the
+    host fast engine."""
     return dict(_DISPATCH)
 
 
-def _record_dispatch(n_traces: int, n_requests: int) -> None:
+def _record_dispatch(n_traces: int, n_requests: int, steps: int) -> None:
     _DISPATCH["dispatches"] += 1
     _DISPATCH["traces"] += n_traces
     _DISPATCH["requests"] += n_requests
+    _DISPATCH["scan_steps"] += steps
 
 
 @dataclasses.dataclass
@@ -183,6 +190,26 @@ def _scan_engine_impl(bank, row, nbanks, tCL, tRCD, tRP, tRC, tBL, lookahead,
     critical path, tRC-limited per bank — and conflicts cannot occur (the
     precharge happens off the critical path, after the previous access).
     """
+    init = (
+        jnp.full((nbanks,), -1, dtype=jnp.int32),
+        jnp.zeros((nbanks,), dtype=jnp.int32),
+        jnp.zeros((nbanks,), dtype=jnp.int32),
+        jnp.full((nbanks,), -(tRC + 1), dtype=jnp.int32),
+        jnp.int32(0),
+        jnp.int32(0),
+        jnp.int32(0),
+        jnp.int32(0),
+    )
+    step = _scan_step(tRCD, tRP, tRC, tBL, lookahead, page_open)
+    carry, _ = jax.lax.scan(step, init, (bank, row))
+    bus_free, hits, misses, conflicts = carry[4], carry[5], carry[6], carry[7]
+    return bus_free + tCL, hits, misses, conflicts
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_step(tRCD, tRP, tRC, tBL, lookahead, page_open):
+    """The scan's step for one timing config: one object per config, so
+    JAX's trace caches hit for every [B, L] shape compiled with it."""
 
     def step(carry, req):
         open_row, row_ready, last_data, last_act, bus_free, hits, misses, conflicts = carry
@@ -222,19 +249,7 @@ def _scan_engine_impl(bank, row, nbanks, tCL, tRCD, tRP, tRC, tBL, lookahead,
         return (open_row, row_ready, last_data, last_act, new_bus_free,
                 hits, misses, conflicts), None
 
-    init = (
-        jnp.full((nbanks,), -1, dtype=jnp.int32),
-        jnp.zeros((nbanks,), dtype=jnp.int32),
-        jnp.zeros((nbanks,), dtype=jnp.int32),
-        jnp.full((nbanks,), -(tRC + 1), dtype=jnp.int32),
-        jnp.int32(0),
-        jnp.int32(0),
-        jnp.int32(0),
-        jnp.int32(0),
-    )
-    carry, _ = jax.lax.scan(step, init, (bank, row))
-    bus_free, hits, misses, conflicts = carry[4], carry[5], carry[6], carry[7]
-    return bus_free + tCL, hits, misses, conflicts
+    return step
 
 
 _ENGINE_STATICS = ("nbanks", "tCL", "tRCD", "tRP", "tRC", "tBL", "lookahead",
@@ -243,15 +258,39 @@ _ENGINE_STATICS = ("nbanks", "tCL", "tRCD", "tRP", "tRC", "tBL", "lookahead",
 _scan_engine = partial(jax.jit, static_argnames=_ENGINE_STATICS)(_scan_engine_impl)
 
 
+# Requests a grid step of the TPU kernel streams into VMEM (a [block, 128]
+# int32 tile per input).
+KERNEL_BLOCK = 512
+
+
+def _kernel_batch(bank, row, **statics):
+    """The lane-major Pallas kernel on [B, L]; L is padded on the device
+    with no-op requests up to a multiple of its block."""
+    # imported when traced: a process that never traces the batched engine
+    # (a server or a client beside the seat) does not pay for Pallas
+    from repro.kernels.dram_timing.dram_timing import SUBLANES, dram_timing_lanes
+
+    L = bank.shape[1]
+    block = min(KERNEL_BLOCK, -(-L // SUBLANES) * SUBLANES)
+    pad = ((0, 0), (0, -L % block))
+    out = dram_timing_lanes(jnp.pad(bank, pad, constant_values=-1),
+                            jnp.pad(row, pad), block=block, **statics)
+    return out[0], out[1], out[2], out[3]
+
+
 @partial(jax.jit, static_argnames=_ENGINE_STATICS)
 def _scan_engine_batch(bank, row, nbanks, tCL, tRCD, tRP, tRC, tBL, lookahead,
                        page_open):
-    """Batched exact engine: vmap of the scan over the leading [B] axis.
-    Returns per-trace (cycles[B], hits[B], misses[B], conflicts[B])."""
-    f = partial(_scan_engine_impl, nbanks=nbanks, tCL=tCL, tRCD=tRCD,
-                tRP=tRP, tRC=tRC, tBL=tBL, lookahead=lookahead,
-                page_open=page_open)
-    return jax.vmap(f)(bank, row)
+    """Batched exact engine on [B, L].  Returns per-trace (cycles[B],
+    hits[B], misses[B], conflicts[B]).  Lowered for a TPU it is the Pallas
+    kernel, one trace a lane; elsewhere the vmap of the scan over [B]."""
+    statics = dict(nbanks=nbanks, tCL=tCL, tRCD=tRCD, tRP=tRP, tRC=tRC,
+                   tBL=tBL, lookahead=lookahead, page_open=page_open)
+    return jax.lax.platform_dependent(
+        bank, row,
+        tpu=partial(_kernel_batch, **statics),
+        default=jax.vmap(partial(_scan_engine_impl, **statics)),
+    )
 
 
 def classify_fast(bank: np.ndarray, row: np.ndarray, nbanks: int,
@@ -390,7 +429,7 @@ def simulate_channel_scan(trace: Trace, cfg: DRAMConfig) -> TimingReport:
         t["tCL"], t["tRCD"], t["tRP"], t["tRC"], t["tBL"],
         lookahead=16 * t["tBL"], page_open=cfg.page_open,
     )
-    _record_dispatch(1, trace.n)
+    _record_dispatch(1, trace.n, len(bank))
     return _channel_report(trace, cfg, int(cycles), int(hits), int(misses),
                            int(conflicts))
 
@@ -545,7 +584,7 @@ def simulate_batch(
     """Time many single-channel traces with a handful of device dispatches.
 
     Traces routed to the scan engine are grouped into power-of-two length
-    buckets; each bucket is one :class:`TraceBatch` and one vmapped
+    buckets; each bucket is one :class:`TraceBatch` and one
     ``_scan_engine_batch`` call (split only past :data:`MAX_BATCH_ELEMS`).
     Fast-engine traces go through one vectorised host-side pass.  Returns
     per-trace reports in input order, identical to calling
@@ -590,7 +629,8 @@ def simulate_batch(
                     t["tBL"], lookahead=16 * t["tBL"],
                     page_open=cfg.page_open,
                 )
-                _record_dispatch(len(chunk), int(batch.lengths.sum()))
+                _record_dispatch(len(chunk), int(batch.lengths.sum()),
+                                 batch.bucket_len)
                 cycles, hits, misses, conflicts = (  # one host sync
                     np.asarray(cycles), np.asarray(hits),
                     np.asarray(misses), np.asarray(conflicts),

@@ -8,8 +8,9 @@ Each kernel directory contains:
 
 Kernels:
 - ``dram_timing``: the DRAM bank state-machine engine, re-designed for TPU
-  as blocked request streaming (HBM->VMEM) with bank state in VMEM scratch
-  carried across sequential grid steps.
+  lane-major: one trace a lane, requests streamed HBM->VMEM in blocks, the
+  bank state a [banks, 128] tile carried across sequential grid steps.  It
+  is what ``repro.core.engine._scan_engine_batch`` lowers to on a TPU.
 - ``spmv``: ELL-blocked sparse matrix-vector multiply (the SpMV graph
   workload, and the compute core of PR).
 - ``edge_update``: edge-centric gather-apply-scatter step (BFS/WCC/SSSP

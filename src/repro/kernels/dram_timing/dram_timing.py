@@ -1,23 +1,28 @@
-"""Pallas TPU kernel: DRAM bank state-machine timing engine.
+"""Pallas TPU kernel: DRAM bank state-machine timing engine, lane-major.
 
 TPU adaptation of Ramulator's sequential bank state machines (see DESIGN.md
-§Hardware adaptation): the request trace is streamed from HBM in blocks
-(BlockSpec tiling) into VMEM; the per-bank state (open row, row-ready time,
-last data slot, last activate) lives in VMEM scratch that persists across
-the *sequential* TPU grid, so each grid step advances the same simulation.
-The per-request dependency chain is resolved with an in-kernel fori_loop
-over the VMEM-resident block (the block is the unit of HBM traffic; the
-serial chain never touches HBM).
+§Hardware adaptation).  Every trace of a ``[B, L]`` batch is one lane of the
+TPU's 128-wide vector axis, so one sequential step advances up to 128
+traces at once:
 
-The grid is two-dimensional: ``(B, n_blocks)``.  The trailing (fastest)
-dimension walks one trace's request blocks sequentially; the leading
-dimension advances to the next trace of the batch, re-initialising the
-VMEM bank state at its first block.  One ``pallas_call`` therefore times a
-whole :class:`repro.core.engine.TraceBatch` — one device dispatch per
-batch, not per trace.
+- the request stream is transposed on the device to ``[L, lanes]`` and
+  streamed from HBM in ``[block, 128]`` VMEM tiles along an ``"arbitrary"``
+  (sequential) grid axis; the serial chain never touches HBM;
+- the per-bank state (open row, row-ready time, last data slot, last
+  activate) is a ``[banks, 128]`` int32 tile, banks on sublanes (padded to a
+  multiple of 8), carried in registers across an in-kernel ``fori_loop`` over
+  the block's requests and kept in VMEM scratch between grid steps;
+- the per-lane counters (bus free, hits, misses, conflicts) are ``[1, 128]``
+  rows;
+- gathers and scatters of the bank state become one-hot selects on
+  ``iota(banks) == bank``: a read is a masked sublane sum, a write a masked
+  ``where``.  A padding request (``bank == -1``) matches no bank, so its
+  reads give 0 and every write and counter stays gated, as in the scan.
 
-Timing semantics are identical to ``repro.core.engine._scan_engine``
-(`ref.py` re-exports it, and its vmapped batch form, as the oracles).
+Batches wider than 128 traces take one lane group per step of a leading
+``"parallel"`` grid axis.  The arithmetic is the int32 ``max``/``where``
+chain of ``repro.core.engine._scan_engine_impl``, so every report equals the
+scan's; ``ref.py`` re-exports the scan, single and vmapped, as the oracles.
 """
 from __future__ import annotations
 
@@ -28,88 +33,149 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-STATE_BANKS_PAD = 128  # lane-aligned bank-state vectors
+LANES = 128  # traces per lane group: the vector unit's lane width
+SUBLANES = 8  # int32 tile height; bank state and request blocks align to it
 
 
-def _kernel(bank_ref, row_ref, out_ref, state_ref, scalars_ref, *, nbanks,
+def _kernel(bank_ref, row_ref, out_ref, state_ref, count_ref, *, banks,
             tCL, tRCD, tRP, tRC, tBL, lookahead, page_open, block, n_blocks):
-    """One grid step: consume `block` requests of one batch row.
+    """One grid step: advance one lane group by ``block`` requests.
 
-    state_ref: (4, STATE_BANKS_PAD) int32 VMEM scratch
-       rows: 0=open_row, 1=row_ready, 2=last_data, 3=last_act
-    scalars_ref: (1, 8) int32 VMEM scratch
-       cols: 0=bus_free, 1=hits, 2=misses, 3=conflicts
-    Scratch persists across the sequential grid; step == 0 of each batch
-    row resets it so every trace starts from a cold, precharged device.
+    state_ref: (4, banks, 128) int32 VMEM scratch, banks on sublanes:
+       0=open_row, 1=row_ready, 2=last_data, 3=last_act
+    count_ref: (8, 128) int32 VMEM scratch, one row per lane counter:
+       0=bus_free, 1=hits, 2=misses, 3=conflicts
+    Scratch persists across the sequential grid axis; its first step
+    resets it, so every lane starts from a cold, precharged device.
     """
     step = pl.program_id(1)
 
     @pl.when(step == 0)
     def _init():
-        state_ref[0, :] = jnp.full((STATE_BANKS_PAD,), -1, dtype=jnp.int32)
-        state_ref[1, :] = jnp.zeros((STATE_BANKS_PAD,), dtype=jnp.int32)
-        state_ref[2, :] = jnp.zeros((STATE_BANKS_PAD,), dtype=jnp.int32)
-        state_ref[3, :] = jnp.full((STATE_BANKS_PAD,), -(tRC + 1), dtype=jnp.int32)
-        scalars_ref[0, :] = jnp.zeros((8,), dtype=jnp.int32)
+        state_ref[0] = jnp.full((banks, LANES), -1, jnp.int32)
+        state_ref[1] = jnp.zeros((banks, LANES), jnp.int32)
+        state_ref[2] = jnp.zeros((banks, LANES), jnp.int32)
+        state_ref[3] = jnp.full((banks, LANES), -(tRC + 1), jnp.int32)
+        count_ref[...] = jnp.zeros((SUBLANES, LANES), jnp.int32)
 
-    banks = bank_ref[0, :]
-    rows = row_ref[0, :]
+    bank_ids = jax.lax.broadcasted_iota(jnp.int32, (banks, LANES), 0)
 
     def body(i, carry):
         open_row, row_ready, last_data, last_act, bus_free, hits, misses, confs = carry
-        b = banks[i]
-        r = rows[i]
+        b = bank_ref[pl.ds(i, 1), :]
+        r = row_ref[pl.ds(i, 1), :]
+        onehot = bank_ids == b  # no bank matches a padding request
         valid = b >= 0
-        bi = jnp.maximum(b, 0)
-        cur = open_row[bi]
+
+        def read(state):
+            return jnp.sum(jnp.where(onehot, state, 0), axis=0, keepdims=True)
+
+        cur = read(open_row)
         if page_open:
             is_hit = (cur == r) & valid
-            is_miss = (cur == jnp.int32(-1)) & valid
+            is_miss = (cur == -1) & valid
             is_conf = valid & ~is_hit & ~is_miss
         else:
             # closed-page policy: every access auto-precharges, so each
             # valid request activates (a miss) and conflicts cannot occur
-            is_hit = jnp.bool_(False) & valid
+            is_hit = jnp.zeros_like(valid)
             is_miss = valid
-            is_conf = jnp.bool_(False) & valid
+            is_conf = jnp.zeros_like(valid)
 
+        ready_b, data_b, act_b = read(row_ready), read(last_data), read(last_act)
         horizon = jnp.maximum(bus_free - lookahead, 0)
-        t_pre = jnp.maximum(last_data[bi], horizon)
-        t_act_conf = jnp.maximum(t_pre + tRP, last_act[bi] + tRC)
-        t_act_miss = jnp.maximum(jnp.maximum(last_act[bi] + tRC, last_data[bi]), horizon)
+        t_pre = jnp.maximum(data_b, horizon)
+        t_act_conf = jnp.maximum(t_pre + tRP, act_b + tRC)
+        t_act_miss = jnp.maximum(jnp.maximum(act_b + tRC, data_b), horizon)
         t_act = jnp.where(is_conf, t_act_conf, t_act_miss)
-        new_row_ready = jnp.where(is_hit, row_ready[bi], t_act + tRCD)
+        new_row_ready = jnp.where(is_hit, ready_b, t_act + tRCD)
 
         slot_start = jnp.maximum(new_row_ready, bus_free)
         slot_end = slot_start + tBL
         bus_free = jnp.where(valid, slot_end, bus_free)
 
-        open_row = jnp.where(valid, open_row.at[bi].set(r), open_row)
-        row_ready = jnp.where(valid, row_ready.at[bi].set(new_row_ready), row_ready)
-        last_data = jnp.where(valid, last_data.at[bi].set(slot_end), last_data)
-        last_act = jnp.where(is_hit | ~valid, last_act, last_act.at[bi].set(t_act))
+        open_row = jnp.where(onehot, r, open_row)
+        row_ready = jnp.where(onehot, new_row_ready, row_ready)
+        last_data = jnp.where(onehot, slot_end, last_data)
+        last_act = jnp.where(onehot & ~is_hit, t_act, last_act)
         return (open_row, row_ready, last_data, last_act, bus_free,
-                hits + is_hit, misses + is_miss, confs + is_conf)
+                hits + is_hit.astype(jnp.int32),
+                misses + is_miss.astype(jnp.int32),
+                confs + is_conf.astype(jnp.int32))
 
-    carry = (
-        state_ref[0, :], state_ref[1, :], state_ref[2, :], state_ref[3, :],
-        scalars_ref[0, 0], scalars_ref[0, 1], scalars_ref[0, 2], scalars_ref[0, 3],
-    )
+    carry = (state_ref[0], state_ref[1], state_ref[2], state_ref[3],
+             count_ref[0:1, :], count_ref[1:2, :], count_ref[2:3, :],
+             count_ref[3:4, :])
+    # not unrolled: a deeper unroll saves a few ns a step on the chip but
+    # multiplies the seconds every program takes to trace and lower
     carry = jax.lax.fori_loop(0, block, body, carry)
-    state_ref[0, :], state_ref[1, :], state_ref[2, :], state_ref[3, :] = carry[:4]
-    scalars_ref[0, 0] = carry[4]
-    scalars_ref[0, 1] = carry[5]
-    scalars_ref[0, 2] = carry[6]
-    scalars_ref[0, 3] = carry[7]
+    state_ref[0], state_ref[1], state_ref[2], state_ref[3] = carry[:4]
+    for k in range(4):
+        count_ref[k:k + 1, :] = carry[4 + k]
 
     @pl.when(step == n_blocks - 1)
     def _finalize():
-        out = jnp.zeros((8,), dtype=jnp.int32)
-        out = out.at[0].set(scalars_ref[0, 0] + tCL)  # total cycles
-        out = out.at[1].set(scalars_ref[0, 1])
-        out = out.at[2].set(scalars_ref[0, 2])
-        out = out.at[3].set(scalars_ref[0, 3])
-        out_ref[0, :] = out
+        out_ref[...] = count_ref[...]
+        out_ref[0:1, :] = count_ref[0:1, :] + tCL  # total cycles
+
+
+def dram_timing_lanes(bank, row, *, nbanks, tCL, tRCD, tRP, tRC, tBL,
+                      lookahead, page_open, block, interpret=False):
+    """Time ``[B, L]`` bank/row request arrays (padding ``bank == -1``; L a
+    multiple of ``block``, ``block`` a multiple of 8) in one ``pallas_call``.
+    Transposes and pads the lanes on the device.  Returns int32[4, B]: per
+    trace (total_cycles, hits, misses, conflicts)."""
+    if bank.ndim != 2 or bank.shape != row.shape:
+        raise ValueError(f"expected [B, L] bank and row, got {bank.shape} "
+                         f"and {row.shape}")
+    b_sz, n = bank.shape
+    if block % SUBLANES or n % block:
+        raise ValueError(f"L={n} must be a multiple of block={block}, and "
+                         f"block a multiple of {SUBLANES}")
+    groups = -(-b_sz // LANES)
+    lanes = ((0, 0), (0, groups * LANES - b_sz))
+    bank_t = jnp.pad(bank.astype(jnp.int32).T, lanes, constant_values=-1)
+    row_t = jnp.pad(row.astype(jnp.int32).T, lanes)
+    out = _lanes_call(
+        bank_t, row_t, banks=-(-nbanks // SUBLANES) * SUBLANES, tCL=tCL,
+        tRCD=tRCD, tRP=tRP, tRC=tRC, tBL=tBL, lookahead=lookahead,
+        page_open=page_open, block=block, interpret=interpret)
+    return out[:4, :b_sz]
+
+
+# A jit of its own: its trace is cached by the padded [L, lanes] shape, so
+# the batch sizes that pad to one lane group trace the kernel once.
+@functools.partial(
+    jax.jit,
+    static_argnames=("banks", "tCL", "tRCD", "tRP", "tRC", "tBL",
+                     "lookahead", "page_open", "block", "interpret"),
+)
+def _lanes_call(bank_t, row_t, *, banks, tCL, tRCD, tRP, tRC, tBL,
+                lookahead, page_open, block, interpret):
+    n, width = bank_t.shape
+    groups, n_blocks = width // LANES, n // block
+    kernel = functools.partial(
+        _kernel, banks=banks, tCL=tCL, tRCD=tRCD, tRP=tRP, tRC=tRC, tBL=tBL,
+        lookahead=lookahead, page_open=page_open, block=block,
+        n_blocks=n_blocks,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid=(groups, n_blocks),
+        in_specs=[
+            pl.BlockSpec((block, LANES), lambda g, i: (i, g)),
+            pl.BlockSpec((block, LANES), lambda g, i: (i, g)),
+        ],
+        out_specs=pl.BlockSpec((SUBLANES, LANES), lambda g, i: (0, g)),
+        out_shape=jax.ShapeDtypeStruct((SUBLANES, groups * LANES), jnp.int32),
+        scratch_shapes=[
+            pltpu.VMEM((4, banks, LANES), jnp.int32),
+            pltpu.VMEM((SUBLANES, LANES), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(bank_t, row_t)
 
 
 @functools.partial(
@@ -139,32 +205,11 @@ def dram_timing_pallas_batch(
     ``page_open=False`` compiles the closed-page variant (every request
     activates; no conflicts) — a trace-time branch, zero cost in-kernel.
     """
-    assert nbanks <= STATE_BANKS_PAD
-    assert bank.ndim == 2, "batched kernel expects [B, L] request arrays"
-    b_sz, n = bank.shape
-    assert n % block == 0, "pad the trace to a multiple of the block size"
-    n_blocks = n // block
-    kernel = functools.partial(
-        _kernel, nbanks=nbanks, tCL=tCL, tRCD=tRCD, tRP=tRP, tRC=tRC,
+    return dram_timing_lanes(
+        bank, row, nbanks=nbanks, tCL=tCL, tRCD=tRCD, tRP=tRP, tRC=tRC,
         tBL=tBL, lookahead=lookahead, page_open=page_open, block=block,
-        n_blocks=n_blocks,
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(b_sz, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda b, i: (b, i)),
-            pl.BlockSpec((1, block), lambda b, i: (b, i)),
-        ],
-        out_specs=pl.BlockSpec((1, 8), lambda b, i: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((b_sz, 8), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM((4, STATE_BANKS_PAD), jnp.int32),
-            pltpu.VMEM((1, 8), jnp.int32),
-        ],
         interpret=interpret,
-    )(bank, row)
-    return out[:, :4]
+    ).T
 
 
 def dram_timing_pallas(

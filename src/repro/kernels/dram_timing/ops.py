@@ -1,7 +1,7 @@
 """Public op: DRAM timing via the Pallas kernel (TPU) or scan oracle (CPU).
 
 ``simulate_trace`` times one trace; ``simulate_trace_batch`` times many in
-ONE device dispatch (batched grid row per trace), matching the batched
+ONE device dispatch (one trace a lane of the kernel), matching the batched
 engine path in ``repro.core.engine.simulate_batch``.
 """
 from __future__ import annotations

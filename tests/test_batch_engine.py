@@ -152,6 +152,32 @@ def test_batched_dispatch_reduction():
     assert n_seq >= 5 * n_bat  # the acceptance-criterion floor
 
 
+def test_dispatch_stats_count_scan_steps(monkeypatch):
+    """``scan_steps`` is the sum of the padded length L of every device
+    dispatch: the sequential steps the scan walks, whatever its lanes
+    hold (also when the batch cap splits a bucket)."""
+    from repro.core import engine
+
+    cfg = dram_config("default")
+    traces = _random_traces(23, [100, 200, 300, 700, 0, 2000, 150])
+    reset_dispatch_stats()
+    simulate_batch(traces, cfg)
+    # buckets 256 (3 traces), 512, 1024, 2048: one dispatch each
+    assert dispatch_stats()["dispatches"] == 4
+    assert dispatch_stats()["scan_steps"] == 256 + 512 + 1024 + 2048
+    monkeypatch.setattr(engine, "MAX_BATCH_ELEMS", 512)
+    reset_dispatch_stats()
+    simulate_batch(traces, cfg)
+    # now 2 lanes a dispatch at L = 256 and one past it: 2 + 1 + 1 + 1
+    assert dispatch_stats()["dispatches"] == 5
+    assert dispatch_stats()["scan_steps"] == 2 * 256 + 512 + 1024 + 2048
+    reset_dispatch_stats()
+    simulate_channel_scan(traces[0], cfg)
+    assert dispatch_stats()["scan_steps"] == 256
+    reset_dispatch_stats()
+    assert dispatch_stats()["scan_steps"] == 0
+
+
 # ---- batched == sequential through the accelerator timing stack -----------
 
 

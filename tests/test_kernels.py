@@ -109,7 +109,7 @@ def test_dram_timing_kernel_matches_scan(dram, n, block):
 
 @pytest.mark.parametrize("dram", ["default", "hbm"])
 def test_dram_timing_kernel_batch_matches_single(dram):
-    """The batched kernel (one grid row per trace, one dispatch for all)
+    """The batched kernel (one lane per trace, one dispatch for all)
     must agree with per-trace kernel calls and the batched scan oracle."""
     cfg = dram_config(dram)
     rng = np.random.default_rng(42)
@@ -144,6 +144,112 @@ def test_dram_timing_kernel_batch_matches_single(dram):
             assert batch[i]["hits"] == ref[i, 1]
             assert batch[i]["misses"] == ref[i, 2]
             assert batch[i]["conflicts"] == ref[i, 3]
+
+
+def _lane_batch(cfg, B, L, seed):
+    """[B, L] bank/row arrays of mixed locality with ragged lanes: for B >=
+    3 lane 1 is empty (all padding) and lane 2 holds one request."""
+    rng = np.random.default_rng(seed)
+    lines = np.where(rng.random((B, L)) < 0.6,
+                     np.arange(L)[None, :] + rng.integers(0, 1 << 20, (B, 1)),
+                     rng.integers(0, 1 << 22, (B, L)))
+    bank, row = decode(lines.ravel(), cfg)
+    bank = bank.reshape(B, L).astype(np.int32)
+    row = row.reshape(B, L).astype(np.int32)
+    lens = rng.integers(0, L + 1, B)
+    lens[0] = L
+    if B >= 3:
+        lens[1], lens[2] = 0, 1
+    bank[np.arange(L)[None, :] >= lens[:, None]] = -1
+    return bank, row
+
+
+def _statics(cfg):
+    t = cfg.timing_cycles()
+    return dict(nbanks=cfg.nbanks, tCL=t["tCL"], tRCD=t["tRCD"], tRP=t["tRP"],
+                tRC=t["tRC"], tBL=t["tBL"], lookahead=16 * t["tBL"],
+                page_open=cfg.page_open)
+
+
+@pytest.mark.parametrize("B", [1, 3, 16])
+@pytest.mark.parametrize("policy", ["open", "closed"])
+@pytest.mark.parametrize("dram", ["default", "ddr3", "hbm", "hitgraph"])
+def test_dram_timing_lanes_matches_vmapped_scan(dram, policy, B):
+    """The lane-major kernel (interpret mode) equals the vmapped scan field
+    for field: every preset (HBM as its pseudo-channel view), both page
+    policies, ragged, empty and one-request lanes."""
+    from repro.kernels.dram_timing.dram_timing import dram_timing_lanes
+
+    cfg = dram_config(dram, page_policy=policy).pseudo_channel_view()
+    statics = _statics(cfg)
+    bank, row = _lane_batch(cfg, B, 256, seed=B)
+    got = np.asarray(dram_timing_lanes(bank, row, block=64, interpret=True,
+                                       **statics))
+    want = np.asarray(dram_timing_ref_batch(bank, row, **statics))
+    np.testing.assert_array_equal(got, want.T)
+
+
+def test_dram_timing_lanes_splits_past_128_lanes():
+    """A batch wider than one lane group takes a grid step per group."""
+    from repro.kernels.dram_timing.dram_timing import dram_timing_lanes
+
+    cfg = dram_config("default")
+    statics = _statics(cfg)
+    bank, row = _lane_batch(cfg, 130, 64, seed=130)
+    got = np.asarray(dram_timing_lanes(bank, row, block=32, interpret=True,
+                                       **statics))
+    want = np.asarray(dram_timing_ref_batch(bank, row, **statics))
+    assert got.shape == (4, 130)
+    np.testing.assert_array_equal(got, want.T)
+
+
+@pytest.mark.parametrize("L,block", [(200, 64), (256, 100)])
+def test_dram_timing_lanes_refuses_ragged_blocks(L, block):
+    """L must be a multiple of the block, and the block of the 8-row tile."""
+    from repro.kernels.dram_timing.dram_timing import dram_timing_lanes
+
+    bank = np.full((2, L), -1, np.int32)
+    with pytest.raises(ValueError, match="multiple of"):
+        dram_timing_lanes(bank, bank, block=block, interpret=True,
+                          **_statics(dram_config("default")))
+
+
+@pytest.mark.parametrize("dram", ["default", "hbm"])
+def test_simulate_batch_through_kernel_matches_sequential(dram, monkeypatch):
+    """``simulate_batch`` with its dispatch routed through the kernel (as
+    lowered for a TPU, here interpreted) reports what the per-trace scan
+    reports, including a bucket L the kernel pads to its block."""
+    from repro.core import engine
+
+    def kernel(bank, row, nbanks, tCL, tRCD, tRP, tRC, tBL, lookahead,
+               page_open):
+        return engine._kernel_batch(
+            bank, row, nbanks=nbanks, tCL=tCL, tRCD=tRCD, tRP=tRP, tRC=tRC,
+            tBL=tBL, lookahead=lookahead, page_open=page_open, interpret=True)
+
+    cfg = dram_config(dram).pseudo_channel_view()
+    rng = np.random.default_rng(7)
+    traces = [Trace(rng.integers(0, 1 << 20, size=n), np.zeros(n, dtype=bool))
+              for n in (1, 250, 300, 700)] + [Trace.empty()]
+    traces.append(Trace(np.arange(900, dtype=np.int64),
+                        np.zeros(900, dtype=bool)))
+    # buckets 512 and 1024 are padded to 768 and 1152 with no-op requests
+    monkeypatch.setattr(engine, "KERNEL_BLOCK", 384)
+    monkeypatch.setattr(engine, "_scan_engine_batch", kernel)
+    batched = engine.simulate_batch(traces, cfg)
+    monkeypatch.undo()
+    assert batched == engine.simulate_sequential(traces, cfg)
+
+
+def test_scan_engine_batch_lowers_scan_on_cpu():
+    """Lowered for the CPU the batched engine is the scan: no kernel."""
+    from repro.core.engine import _scan_engine_batch
+
+    req = jax.ShapeDtypeStruct((4, 256), jnp.int32)
+    text = _scan_engine_batch.lower(
+        req, req, **_statics(dram_config("default"))).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert "while" in text
 
 
 # ---------------------------------------------------------------------------

@@ -308,6 +308,9 @@ def test_scheduler_counts_seat_device_work(tmp_path):
         assert c["worker_device_dispatches"] >= 1
         assert c["worker_device_traces"] >= 2
         assert c["worker_device_requests"] > 0
+        # each dispatch walks its bucket length L, at least the 256 floor
+        assert c["worker_device_scan_steps"] >= \
+            256 * c["worker_device_dispatches"]
         assert c["worker_device_host_traces"] == 0  # all under SCAN_CUTOFF
         assert c.get("timing_fallbacks", 0) == 0
     finally:

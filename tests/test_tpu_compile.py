@@ -4,7 +4,8 @@ Nothing here runs on a chip: the TPU compiler, which ships with jaxlib,
 compiles for a topology that is described and not attached, and refuses
 what the chip would refuse (unsupported gathers in Pallas kernels, tiling
 violations, programs that do not fit).  Shapes are real: the batched
-DRAM-timing scan at its ``MAX_BATCH_ELEMS`` shape, and every semexec
+DRAM-timing engine, which lowers to its Pallas kernel for the chip, at its
+``MAX_BATCH_ELEMS`` shapes, and every semexec
 device step with the argument shapes of the ``lj`` graph's layouts, taken
 from the accelerators' own layout builders.
 
@@ -70,6 +71,36 @@ def test_scan_engine_batch_compiles(one_chip, page_policy):
         t["tBL"], lookahead=16 * t["tBL"], page_open=cfg.page_open,
     ).compile()
     assert compiled.memory_analysis() is not None
+    assert "tpu_custom_call" in compiled.as_text()  # the Pallas kernel
+
+
+# (DRAM preset, page policy, HBM pseudo-channels): DDR4's 16 banks both
+# ways, and the 8-bank pseudo-channel view the HBM rows are timed on
+MEMORIES = [("default", "open", False), ("default", "closed", False),
+            ("hbm", "closed", True)]
+
+
+@pytest.mark.parametrize("dram,page_policy,pseudo_channels", MEMORIES)
+def test_scan_kernel_compiles_at_largest_warm_shape(one_chip, dram,
+                                                    page_policy,
+                                                    pseudo_channels):
+    """The largest shape the benchmark's warm-up compiles (L = 262,144 at
+    ``MAX_BATCH_ELEMS // L`` lanes) lowers to the kernel and fits."""
+    from repro.core.dram import dram_config
+    from repro.core.engine import MAX_BATCH_ELEMS, _scan_engine_batch
+
+    cfg = dram_config(dram, page_policy=page_policy,
+                      pseudo_channels=pseudo_channels).pseudo_channel_view()
+    t = cfg.timing_cycles()
+    L = 262144
+    req = jax.ShapeDtypeStruct((MAX_BATCH_ELEMS // L, L), jnp.int32,
+                               sharding=one_chip)
+    compiled = _scan_engine_batch.lower(
+        req, req, cfg.nbanks, t["tCL"], t["tRCD"], t["tRP"], t["tRC"],
+        t["tBL"], lookahead=16 * t["tBL"], page_open=cfg.page_open,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
 class _Captured(Exception):
