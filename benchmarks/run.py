@@ -1,5 +1,4 @@
-"""Benchmark harness: one bench per paper table/figure + the LM-side
-roofline summary.
+"""Benchmark harness: one bench per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run                  # everything
     PYTHONPATH=src python -m benchmarks.run --benches tab4,fig9 --graphs sd,db
@@ -15,7 +14,6 @@ Benches (paper artifact -> bench):
     fig9      Fig.9          : critical metrics (iterations, bytes/edge, ...)
     fig10     Fig.10/14      : MREPS by skew / average degree
     kernels   (framework)    : Pallas-kernel micro-bench, us_per_call
-    roofline  (framework)    : summarize results/dryrun into the roofline CSV
 
 Every paper bench is a thin ``SweepSpec`` executed through
 ``repro.sweep.run_sweep``: results are content-address cached (re-running a
@@ -289,11 +287,7 @@ def bench_fig10(graphs, out, validation, sweep):
 def bench_kernels(graphs, out, validation, sweep):
     """Micro-bench: name,us_per_call for each Pallas kernel (interpret mode
     on CPU — correctness-path timing, not TPU perf) and its oracle."""
-    import jax
-    import jax.numpy as jnp
-
     from repro.graph.generators import uniform_random
-    from repro.kernels.attention.ops import flash_attention
     from repro.kernels.dram_timing.ops import simulate_trace
     from repro.kernels.edge_update.ops import relax_step
     from repro.kernels.spmv.ops import spmv
@@ -322,46 +316,9 @@ def bench_kernels(graphs, out, validation, sweep):
     timeit("dram_timing_pallas_interp",
            lambda: simulate_trace(tr, cfg, use_pallas=True, interpret=True))
     timeit("dram_timing_ref", lambda: simulate_trace(tr, cfg, use_pallas=False))
-    rng = np.random.default_rng(1)
-    q = jnp.asarray(rng.normal(size=(1, 256, 4, 64)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(1, 256, 2, 64)), jnp.float32)
-    vv = jnp.asarray(rng.normal(size=(1, 256, 2, 64)), jnp.float32)
-    timeit("flash_attention_pallas_interp",
-           lambda: flash_attention(q, k, vv, interpret=True).block_until_ready())
     _write(os.path.join(out, "kernels_microbench.csv"), rows)
     for r in rows:
         print(f"  {r['name']},{r['us_per_call']}")
-
-
-def bench_roofline(graphs, out, validation, sweep, dryrun_dir="results/dryrun"):
-    """Summarize the dry-run JSONs into the EXPERIMENTS.md roofline table."""
-    rows = []
-    for mesh in ("single", "multi"):
-        d = os.path.join(dryrun_dir, mesh)
-        if not os.path.isdir(d):
-            continue
-        for fn in sorted(os.listdir(d)):
-            rec = json.load(open(os.path.join(d, fn)))
-            if rec["status"] != "ok":
-                continue
-            r = rec["roofline"]
-            rows.append(dict(
-                arch=rec["arch"], shape=rec["shape"], mesh=mesh,
-                step=rec["step_kind"],
-                compute_ms=round(r["compute_s"] * 1e3, 2),
-                memory_ms=round(r["memory_s"] * 1e3, 2),
-                collective_ms=round(r["collective_s"] * 1e3, 2),
-                dominant=r["dominant"],
-                useful_flops_ratio=round(rec.get("useful_flops_ratio") or 0, 3),
-                temp_gib=round(rec["memory"].get("temp_bytes", 0) / 2**30, 2),
-            ))
-    _write(os.path.join(out, "roofline_summary.csv"), rows)
-    if rows:
-        dom = {}
-        for r in rows:
-            dom[r["dominant"]] = dom.get(r["dominant"], 0) + 1
-        validation["roofline_cells"] = len(rows)
-        validation["roofline_dominant_histogram"] = dom
 
 
 BENCHES = {
@@ -373,7 +330,6 @@ BENCHES = {
     "fig9": bench_fig9,
     "fig10": bench_fig10,
     "kernels": bench_kernels,
-    "roofline": bench_roofline,
 }
 
 

@@ -1,5 +1,3 @@
-"""Config registry: the 10 assigned LM architectures + the paper's own
-graph-accelerator simulation presets."""
-from repro.configs.base import ArchConfig, ARCH_REGISTRY, get_arch, list_archs
-
-__all__ = ["ArchConfig", "ARCH_REGISTRY", "get_arch", "list_archs"]
+"""Presets for the paper's graph-accelerator simulations: each
+accelerator model's default :class:`~repro.core.accelerators.base.AccelConfig`
+at the scaled interval sizes (:mod:`repro.configs.graphsim`)."""

@@ -1,23 +1,19 @@
-"""Distribution: mesh axes, parameter/activation/cache sharding rules,
-collective helpers for the production meshes (single-pod 16x16, multi-pod
-2x16x16), the persistent spawn-based worker pool the sweep server shards
-scenario chunks across (:mod:`repro.distributed.workpool`), its
+"""Distribution: the persistent spawn-based worker pool the sweep server
+shards scenario chunks across (:mod:`repro.distributed.workpool`), its
 multi-host counterpart that dispatches chunks to remote worker hosts
 over the serve wire format (:mod:`repro.distributed.remote`), and the
 deterministic fault-injection harness that exercises their recovery
 paths (:mod:`repro.distributed.faults`).
 
-Exports resolve lazily: :mod:`~repro.distributed.sharding` pulls in jax,
-and spawn-context worker children import this package on their way to
-``workpool`` — they must not pay (or require) the jax import just to run
-the worker loop.
+Exports resolve lazily: :mod:`~repro.distributed.remote` pulls in jax
+(through :mod:`repro.serve`), and spawn-context worker children import
+this package on their way to ``workpool`` — they must not pay (or
+require) the jax import just to run the worker loop.
 """
 from __future__ import annotations
 
 __all__ = ["WorkerPool", "WorkerLost", "RemoteWorkerPool",
-           "WorkerHostAgent", "FaultPlan", "FaultRule",
-           "batch_axes", "batch_specs", "cache_specs", "param_specs",
-           "shardings"]
+           "WorkerHostAgent", "FaultPlan", "FaultRule"]
 
 _LAZY = {
     "WorkerPool": ("repro.distributed.workpool", "WorkerPool"),
@@ -26,11 +22,6 @@ _LAZY = {
     "WorkerHostAgent": ("repro.distributed.remote", "WorkerHostAgent"),
     "FaultPlan": ("repro.distributed.faults", "FaultPlan"),
     "FaultRule": ("repro.distributed.faults", "FaultRule"),
-    "batch_axes": ("repro.distributed.sharding", "batch_axes"),
-    "batch_specs": ("repro.distributed.sharding", "batch_specs"),
-    "cache_specs": ("repro.distributed.sharding", "cache_specs"),
-    "param_specs": ("repro.distributed.sharding", "param_specs"),
-    "shardings": ("repro.distributed.sharding", "shardings"),
 }
 
 

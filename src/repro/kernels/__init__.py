@@ -15,7 +15,4 @@ Kernels:
   workload, and the compute core of PR).
 - ``edge_update``: edge-centric gather-apply-scatter step (BFS/WCC/SSSP
   min-propagation) over edge blocks.
-- ``attention``: blocked causal flash-attention forward (LM serving
-  hot-spot; the dry-run model code keeps XLA einsum attention so
-  cost_analysis stays interpretable — see DESIGN.md).
 """

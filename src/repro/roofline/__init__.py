@@ -1,11 +1,10 @@
-"""Roofline analysis: compute / memory / collective terms derived from the
-dry-run's compiled artifacts (no real hardware)."""
+"""Roofline analysis: compute / memory / collective terms derived from
+compiled XLA programs (no real hardware)."""
 from repro.roofline.analysis import (
     HW,
     HardwareSpec,
     collective_bytes,
-    model_flops,
     roofline_terms,
 )
 
-__all__ = ["HW", "HardwareSpec", "collective_bytes", "model_flops", "roofline_terms"]
+__all__ = ["HW", "HardwareSpec", "collective_bytes", "roofline_terms"]

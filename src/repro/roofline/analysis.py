@@ -1,4 +1,4 @@
-"""Roofline terms from compiled dry-run artifacts.
+"""Roofline terms from compiled XLA programs.
 
     compute term    = HLO_FLOPs / peak_FLOP/s          (per chip)
     memory term     = HLO_bytes / HBM_bw               (per chip)
@@ -110,10 +110,3 @@ def roofline_terms(
         # fraction of the bound attributable to useful compute
         "compute_fraction_of_bound": ct / bound if bound > 0 else 0.0,
     }
-
-
-def model_flops(cfg, tokens: int, kind: str = "train") -> float:
-    """MODEL_FLOPS = 6 N D (dense) / 6 N_active D (MoE); 2 N D for inference."""
-    n = cfg.active_param_count() if cfg.n_experts else cfg.param_count()
-    mult = 6.0 if kind == "train" else 2.0
-    return mult * n * tokens

@@ -40,9 +40,6 @@ poison-scenario circuit breaker), accepted jobs are journaled
 work from the journal plus the cache, and every recovery path is
 exercised deterministically through
 :mod:`repro.distributed.faults`.
-
-The seed's LLM-serving scaffolding (batched KV-cache engine) lives on in
-:mod:`repro.serve.legacy`.
 """
 from repro.serve.client import (
     JobResult,

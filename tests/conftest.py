@@ -1,8 +1,7 @@
 import os
 import sys
 
-# Tests run on the single real CPU device (the 512-device placeholder env is
-# set ONLY inside launch/dryrun.py, never globally).
+# Tests run on the single real CPU device.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
@@ -13,7 +12,7 @@ from repro.graph.generators import rmat, grid_road, preferential
 
 def pytest_configure(config):
     config.addinivalue_line(
-        "markers", "slow: long-running test (dry-run compiles, full drivers)"
+        "markers", "slow: long-running test (full drivers)"
     )
 
 

@@ -12,8 +12,6 @@ from repro.core.dram import dram_config
 from repro.core.engine import decode
 from repro.core.trace import Trace
 from repro.graph.generators import rmat, uniform_random
-from repro.kernels.attention.ops import flash_attention
-from repro.kernels.attention.ref import attention_ref
 from repro.kernels.dram_timing.ops import simulate_trace, simulate_trace_batch
 from repro.kernels.dram_timing.ref import dram_timing_ref, dram_timing_ref_batch
 from repro.kernels.edge_update.edge_update import sentinel_max
@@ -21,60 +19,6 @@ from repro.kernels.edge_update.ops import relax_step, scatter_min
 from repro.kernels.edge_update.ref import edge_update_ref
 from repro.kernels.spmv.ops import spmv, spmv_edges
 from repro.kernels.spmv.ref import spmv_coo_ref
-
-
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize(
-    "b,s,nq,nkv,hd",
-    [
-        (1, 128, 2, 2, 64),
-        (2, 256, 4, 2, 64),   # GQA group 2
-        (1, 256, 4, 1, 32),   # MQA, head_dim padding 32 -> 128
-        (2, 384, 8, 8, 128),  # seq padding 384 -> 512 under 128-blocks
-    ],
-)
-def test_flash_attention_matches_ref(b, s, nq, nkv, hd, dtype):
-    rng = np.random.default_rng(hash((b, s, nq, nkv, hd)) % 2**31)
-    q = jnp.asarray(rng.normal(size=(b, s, nq, hd)), dtype)
-    k = jnp.asarray(rng.normal(size=(b, s, nkv, hd)), dtype)
-    v = jnp.asarray(rng.normal(size=(b, s, nkv, hd)), dtype)
-    out = flash_attention(q, k, v, causal=True, interpret=True)
-    # oracle on expanded heads
-    group = nq // nkv
-    ke = jnp.repeat(k, group, axis=2)
-    ve = jnp.repeat(v, group, axis=2)
-
-    def flat(t):
-        return jnp.moveaxis(t, 2, 1).reshape(b * nq, s, hd)
-
-    ref = attention_ref(flat(q), flat(ke), flat(ve), causal=True)
-    ref = jnp.moveaxis(ref.reshape(b, nq, s, hd), 1, 2).reshape(b, s, nq * hd)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32),
-        rtol=tol, atol=tol,
-    )
-
-
-def test_flash_attention_matches_model_sdpa():
-    """The kernel must agree with the model's einsum attention math."""
-    from repro.models.attention import _sdpa, causal_mask
-
-    rng = np.random.default_rng(0)
-    b, s, nq, nkv, hd = 2, 128, 4, 2, 64
-    q = jnp.asarray(rng.normal(size=(b, s, nq, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(b, s, nkv, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(b, s, nkv, hd)), jnp.float32)
-    model_out = _sdpa(q, k, v, causal_mask(s, s))
-    kern_out = flash_attention(q, k, v, causal=True, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(kern_out), np.asarray(model_out), rtol=2e-5, atol=2e-5
-    )
 
 
 # ---------------------------------------------------------------------------

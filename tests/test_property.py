@@ -335,45 +335,6 @@ def test_accelerators_reach_reference_fixed_point(edges, prob, accel):
 
 
 # ---------------------------------------------------------------------------
-# sharding invariants
-# ---------------------------------------------------------------------------
-
-
-@given(st.integers(1, 4096), st.tuples(st.sampled_from([1, 2, 4, 8, 16]),
-                                       st.sampled_from([1, 2, 4, 8, 16])))
-def test_effective_batch_axes_product_divides(batch, sizes):
-    from repro.distributed import sharding as shd
-
-    class FakeMesh:
-        axis_names = ("pod", "data", "model")
-        devices = np.empty((sizes[0], sizes[1], 2), dtype=object)
-
-    axes = shd.effective_batch_axes(FakeMesh(), batch)
-    prod = 1
-    d = dict(zip(FakeMesh.axis_names, FakeMesh.devices.shape))
-    for a in axes:
-        prod *= d[a]
-    assert batch % prod == 0
-
-
-@given(st.tuples(st.integers(1, 200), st.integers(1, 200)),
-       st.sampled_from([(1, 1), (4, 2), (16, 16)]))
-def test_divisible_spec_always_divides(shape, mesh_shape):
-    from jax.sharding import PartitionSpec as P
-    from repro.distributed import sharding as shd
-
-    class FakeMesh:
-        axis_names = ("data", "model")
-        devices = np.empty(mesh_shape, dtype=object)
-
-    spec = shd._divisible_spec(P("data", "model"), shape, FakeMesh())
-    d = dict(zip(FakeMesh.axis_names, mesh_shape))
-    for dim, entry in enumerate(spec):
-        if entry is not None:
-            assert shape[dim] % d[entry] == 0
-
-
-# ---------------------------------------------------------------------------
 # kernel oracles (scatter_min / spmv_edges vs their numpy references)
 # ---------------------------------------------------------------------------
 

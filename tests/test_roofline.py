@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.roofline.analysis import HW, model_flops, roofline_terms
+from repro.roofline.analysis import roofline_terms
 from repro.roofline.hlo import analyze_hlo, shape_bytes, top_ops
 
 
@@ -110,12 +110,3 @@ def test_roofline_terms_dominance():
     assert t["dominant"] == "collective"
     assert abs(t["memory_s"] - 1.0) < 1e-9 and abs(t["collective_s"] - 2.0) < 1e-9
 
-
-def test_model_flops_moe_uses_active():
-    from repro.configs.base import get_arch
-
-    arctic = get_arch("arctic_480b")
-    assert arctic.param_count() > 4e11
-    assert arctic.active_param_count() < 0.1 * arctic.param_count()
-    d = 1_000_000
-    assert model_flops(arctic, d, "train") == 6 * arctic.active_param_count() * d

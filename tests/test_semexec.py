@@ -9,6 +9,9 @@ The device engine's contract against the numpy oracle:
 - a requested "device" engine on an unsupported accelerator/problem pair
   falls back to numpy with a one-time warning and the layout records the
   engine that actually ran.
+
+The contract holds under every layout axis: vertex reordering, the
+PE/channel count and the interval scale.
 """
 from __future__ import annotations
 
@@ -35,10 +38,13 @@ def tiny_graph():
     return GraphSpec("tiny", "uniform", 256, 1024, True, 1, 0).build()
 
 
-def _prepare(accel: str, g, problem_name: str, engine: str):
+def _prepare(accel: str, g, problem_name: str, engine: str, **layout):
+    """Prepare at a small layout; ``layout`` overrides AccelConfig fields
+    (reorder, n_pes, interval_scale) after the defaults below."""
     cfg = default_config(accel)
     import dataclasses
     cfg = dataclasses.replace(cfg, interval_size=64, n_pes=2, semexec=engine)
+    cfg = dataclasses.replace(cfg, **layout)
     return ACCELERATORS[accel](cfg).prepare(g, PROBLEMS[problem_name],
                                             root=g.degrees_out.argmax())
 
@@ -50,23 +56,34 @@ def kron_graph():
     return GraphSpec("kron", "rmat", 256, 4096, False, 16, 0).build()
 
 
+# the partitioning axes architects sweep, one at a time off the default
+LAYOUTS = [dict(reorder="degree"), dict(reorder="random"),
+           dict(reorder="bfs"), dict(n_pes=1), dict(n_pes=4),
+           dict(interval_scale=2)]
+
 # every pair on the uniform graph (integer weights where weighted) and on
-# the weighted Kronecker graph; the uniform cases keep their ids
-GRAPH_COMBOS = ([pytest.param(a, p, "uniform", id=f"{a}-{p}")
+# the weighted Kronecker graph, then every pair on the Kronecker graph
+# under each layout; the default-layout cases keep their ids
+GRAPH_COMBOS = ([pytest.param(a, p, "uniform", {}, id=f"{a}-{p}")
                  for a, p in COMBOS]
-                + [pytest.param(a, p, "kronecker", id=f"{a}-{p}-kronecker")
+                + [pytest.param(a, p, "kronecker", {}, id=f"{a}-{p}-kronecker")
+                   for a, p in COMBOS]
+                + [pytest.param(a, p, "kronecker", lay,
+                                id=f"{a}-{p}-kronecker-{k}{v}")
+                   for lay in LAYOUTS for (k, v) in lay.items()
                    for a, p in COMBOS])
 
 
-@pytest.mark.parametrize("accel,prob,graph", GRAPH_COMBOS)
-def test_device_matches_numpy(accel, prob, graph, tiny_graph, kron_graph):
+@pytest.mark.parametrize("accel,prob,graph,layout", GRAPH_COMBOS)
+def test_device_matches_numpy(accel, prob, graph, layout, tiny_graph,
+                              kron_graph):
     if graph == "kronecker":
         g = kron_graph
     else:
         g = (tiny_graph.with_weights() if PROBLEMS[prob].needs_weights
              else tiny_graph)
-    host = _prepare(accel, g, prob, "numpy")
-    dev = _prepare(accel, g, prob, "device")
+    host = _prepare(accel, g, prob, "numpy", **layout)
+    dev = _prepare(accel, g, prob, "device", **layout)
     assert host.layout["engine"] == "numpy"
     assert dev.layout["engine"] == "device"
     assert host.iterations == dev.iterations
