@@ -28,12 +28,13 @@ Byte identity contract (tests/test_semexec.py):
 
 Reductions: every backend runs the same program.  The edge layouts are
 static across iterations, so every per-segment min/sum/max is
-precomputed host-side into a *reduce plan* — degree-class gather tables
-(a bucketed-ELL layout of the reduction) — and evaluated as pure
-gathers + dense row reductions, with no scatter over edges.  See
-:func:`build_reduce_plan`.  (The Pallas
-kernels under ``repro.kernels`` are not on this path: the TPU compiler
-refuses their 1-D gathers.)
+precomputed host-side into a *reduce plan*: the segments grouped into
+width classes, one dense block of slots each, with every per-edge input
+stored by the layout builders in those blocks (*table order*).  A
+reduction is then a dense row reduction of each block and one gather over
+the segments, with no scatter and no gather over edges.  See
+:func:`build_reduce_plan`.  (The Pallas kernels under ``repro.kernels``
+are not on this path: the TPU compiler refuses their 1-D gathers.)
 
 ``resolve_engine`` maps a requested engine to the effective one: combos
 without a device formulation fall back to numpy with a one-time warning.
@@ -42,6 +43,7 @@ Per-graph padded device layouts are built once and cached in
 """
 from __future__ import annotations
 
+import dataclasses
 import warnings
 from functools import partial
 
@@ -64,19 +66,23 @@ SUPPORTED: dict[str, frozenset] = {
     "foregraph": frozenset({"bfs", "wcc", "pr"}),
 }
 
-_EDGE_BLOCK = 1024  # edge arrays pad to a multiple of this
-
 _FALLBACK_WARNED: set[tuple[str, str]] = set()
 
 # jitted step dispatches in this process; serve seats report the change
 # over each chunk beside the timing engine's dispatch counters
-_STEPS = dict(semexec_steps=0)
+_STEPS = dict(semexec_steps=0, semexec_gathered=0)
 
 
 def step_stats() -> dict:
     """``semexec_steps``: the jitted per-iteration (or per-partition)
-    steps this process has dispatched."""
+    steps this process has dispatched; ``semexec_gathered``: the index
+    elements those steps gathered (each layout's static count a step)."""
     return dict(_STEPS)
+
+
+def _dispatched(gathered: int) -> None:
+    _STEPS["semexec_steps"] += 1
+    _STEPS["semexec_gathered"] += gathered
 
 
 def validate_engine(engine: str) -> None:
@@ -111,25 +117,8 @@ def to_host(x) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# padding helpers (host-side, one-time per graph layout)
+# per-edge deltas and weights (host-side, one-time per graph layout)
 # ---------------------------------------------------------------------------
-
-
-def _pow2(x: int, lo: int = 8) -> int:
-    p = lo
-    while p < x:
-        p <<= 1
-    return p
-
-
-def _pad_to(a: np.ndarray, length: int, fill, dtype) -> np.ndarray:
-    out = np.full(length, fill, dtype=dtype)
-    out[: len(a)] = a
-    return out
-
-
-def _block_len(m: int) -> int:
-    return max(-(-m // _EDGE_BLOCK) * _EDGE_BLOCK, _EDGE_BLOCK)
 
 
 def _min_delta(problem_name: str, w: np.ndarray | None, m: int) -> np.ndarray:
@@ -156,79 +145,191 @@ def _acc_weight(problem_name: str, src: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# reduce plans: scatter-free segment reductions
+# reduce plans: segment reductions in table order
 # ---------------------------------------------------------------------------
 #
 # XLA's CPU scatter lowering is a serial per-element loop (~8x slower than
 # numpy's ufunc.at on this class of workload), and the TPU compiler refuses
 # the Pallas scatter kernels' 1-D gathers.  But the segment-id arrays here
-# (destination vertex, partition id, run id) are *static* across
-# iterations, so the reduction structure can be precomputed host-side once
-# per layout:
+# (destination vertex, (source partition, destination) pair, interval) are
+# *static* across iterations, so the reduction structure is precomputed
+# host-side once per layout:
 #
-# - sort edge positions by segment id (stable), bucket the non-empty
-#   segments by power-of-two degree class,
-# - per class, store a [rows, K] gather table of edge positions, padded
-#   with a sentinel position m that indexes an identity slot appended to
-#   the per-edge candidate array,
-# - store a static inverse gather ``inv`` mapping every segment id to its
-#   row in the concatenated per-class results (empty segments map to a
-#   trailing identity slot).
+# - the non-empty segments are grouped into at most ``_MAX_CLASSES`` width
+#   classes (:func:`_class_widths`); each class is a dense block of slots,
+#   one row of ``width`` slots a segment, holding the positions of the
+#   segment's inputs and -1 for padding;
+# - the layout builders store every per-edge input in those blocks
+#   (:func:`table_order`) with identity padding, so a reduction is one
+#   dense row reduction a block, with no gather over edges;
+# - a static inverse gather ``inv`` maps every segment id to its row in the
+#   concatenated per-class results (empty segments map to a trailing
+#   identity slot).
 #
-# Evaluation is then pure gathers + dense row reductions — no scatter at
-# all — and is exact for min (order-independent) while sums associate in
-# a fixed per-row tree order (covered by the acc allclose contract).
+# A block with at least as many rows as slots a row is stored transposed,
+# [width, rows], so that its long axis lies along the TPU's lanes.  A plan
+# over a vector the step computes (a second stage over another plan's rows,
+# vertices to intervals) gathers the vector into its blocks instead, and
+# such a plan of a single segment is a plain reduction of the vector.
+#
+# Min is exact (order-independent); sums associate in a fixed per-row tree
+# order (covered by the acc allclose contract).
+
+_MAX_CLASSES = 16
 
 
-def build_reduce_plan(seg: np.ndarray, num_segments: int):
-    """Precompute a scatter-free segment-reduction plan for a static
-    segment-id array.  Returns ``(tables, inv)``: a tuple of int32 gather
-    tables (one per degree class, padded with sentinel ``len(seg)``) and
-    the int32 inverse gather over segment ids."""
-    seg = np.asarray(seg)
+def _class_widths(sizes: np.ndarray) -> np.ndarray:
+    """Ascending row widths for the positive segment sizes ``sizes``: at
+    most ``_MAX_CLASSES`` widths that minimise the blocks' slots, each
+    segment in the narrowest width that holds it.  Dynamic programming over
+    the distinct sizes, rounded up to 32 steps an octave where there are
+    more than 512 of them."""
+    u, c = np.unique(sizes, return_counts=True)
+    if len(u) > 512:
+        step = 1 << np.maximum(np.log2(u).astype(np.int64) - 5, 0)
+        u, back = np.unique(-(-u // step) * step, return_inverse=True)
+        c = np.bincount(back, weights=c)
+    if len(u) <= _MAX_CLASSES:
+        return u
+    d = len(u)
+    cc = np.concatenate([[0], np.cumsum(c)])
+    a, b = np.arange(d + 1)[:, None], np.arange(d + 1)[None, :]
+    # slots[a, b]: one class holding the sizes u[a:b], of width u[b - 1]
+    slots = np.where(a < b, u[np.maximum(b - 1, 0)] * (cc[b] - cc[a]),
+                     np.inf)
+    best = np.full(d + 1, np.inf)
+    best[0] = 0.0
+    args = []
+    for _ in range(_MAX_CLASSES):
+        total = best[:, None] + slots
+        args.append(total.argmin(axis=0))
+        best = total.min(axis=0)
+    widths, j = [], d
+    for arg in reversed(args):
+        widths.append(u[j - 1])
+        j = arg[j]
+    return np.array(widths[::-1])
+
+
+@partial(jax.tree_util.register_dataclass, data_fields=["take", "inv"],
+         meta_fields=["axes", "num_segments", "whole"])
+@dataclasses.dataclass(frozen=True)
+class ReducePlan:
+    """A static segment reduction (see the notes above).  ``take``: for a
+    plan over a vector, each block's gather from it (padding reads an
+    identity appended to it), else empty; ``inv``: the row of each segment
+    (None where there is no block); ``axes``: each block's reduced axis;
+    ``whole``: a plan over a vector of one segment, a plain reduction."""
+    take: tuple
+    inv: jax.Array | None
+    axes: tuple
+    num_segments: int
+    whole: bool = False
+
+    def gathered(self) -> int:
+        """Index elements an evaluation of the plan gathers."""
+        n = sum(int(t.size) for t in self.take)
+        return n + (0 if self.inv is None else int(self.inv.size))
+
+
+def build_reduce_plan(seg, num_segments: int, *, over_vector: bool = False):
+    """Precompute the reduction of inputs grouped by the static segment ids
+    ``seg`` into ``num_segments`` results.  Returns ``(plan, slots)``:
+    ``slots`` holds per block the position in ``seg`` of each slot's input
+    (-1: padding), the order :func:`table_order` stores per-edge inputs
+    in.  With ``over_vector`` the plan gathers its blocks from the vector
+    it is evaluated on, for vectors the step computes."""
+    seg = np.asarray(seg, dtype=np.int64)
     m = len(seg)
-    order = np.argsort(seg, kind="stable")
-    counts = np.bincount(seg, minlength=num_segments) if m else \
-        np.zeros(num_segments, dtype=np.int64)
-    ptr = np.zeros(num_segments + 1, dtype=np.int64)
-    ptr[1:] = np.cumsum(counts)
+    if over_vector and num_segments == 1:
+        return ReducePlan((), None, (), 1, whole=True), ()
+    counts = np.bincount(seg, minlength=num_segments)
     nz = np.flatnonzero(counts)
-    tables: list = []
-    offsets = np.full(num_segments, -1, dtype=np.int64)
-    total = 0
-    if len(nz):
-        deg = counts[nz]
-        cls = np.ceil(np.log2(deg)).astype(np.int64)  # deg <= 2**cls
-        for c in np.unique(cls):
-            K = 1 << int(c)
-            rows = nz[cls == c]
-            base = ptr[rows][:, None] + np.arange(K)[None, :]
-            live = np.arange(K)[None, :] < counts[rows][:, None]
-            tbl = np.full(base.shape, m, dtype=np.int64)
-            tbl[live] = order[base[live]]
-            tables.append(jnp.asarray(tbl.astype(np.int32)))
-            offsets[rows] = total + np.arange(len(rows))
-            total += len(rows)
-    inv = np.where(offsets >= 0, offsets, total).astype(np.int32)
-    return tuple(tables), jnp.asarray(inv)
+    if not len(nz):
+        return ReducePlan((), None, (), num_segments), ()
+    order = np.argsort(seg, kind="stable")
+    ptr = np.concatenate([[0], np.cumsum(counts)])
+    widths = _class_widths(counts[nz])
+    cls = np.searchsorted(widths, counts[nz])
+    inv = np.full(num_segments, len(nz), dtype=np.int32)
+    slots, axes, total = [], [], 0
+    for c in np.unique(cls):
+        width = int(widths[c])
+        rows = nz[cls == c]
+        col = np.arange(width)[None, :]
+        live = col < counts[rows][:, None]
+        blk = np.full((len(rows), width), -1, dtype=np.int64)
+        blk[live] = order[(ptr[rows][:, None] + col)[live]]
+        if len(rows) >= width:  # transposed: the long axis on the lanes
+            slots.append(np.ascontiguousarray(blk.T))
+            axes.append(0)
+        else:
+            slots.append(blk)
+            axes.append(1)
+        inv[rows] = total + np.arange(len(rows))
+        total += len(rows)
+    take = tuple(jnp.asarray(np.where(s >= 0, s, m).astype(np.int32))
+                 for s in slots) if over_vector else ()
+    return (ReducePlan(take, jnp.asarray(inv), tuple(axes), num_segments),
+            tuple(slots))
+
+
+def table_order(slots, a, fill, dtype) -> tuple:
+    """``a`` stored in a plan's table order: per block, the input of each
+    slot, ``fill`` in padding, on the device."""
+    a = np.asarray(a)
+    return tuple(
+        jnp.asarray(np.where(s >= 0, a[np.maximum(s, 0)], fill).astype(dtype))
+        for s in slots)
+
+
+def row_segments(plan: ReducePlan, slots, seg) -> np.ndarray:
+    """The segment id of each row of :func:`plan_rows`' results, for a
+    second stage over the rows."""
+    seg = np.asarray(seg)
+    return np.concatenate(
+        [seg[s[0] if ax == 0 else s[:, 0]] for s, ax in zip(slots, plan.axes)]
+        + [np.zeros(0, dtype=seg.dtype)])
 
 
 _PLAN_IDENTITY = {"min": np.inf, "sum": 0, "max": 0}
 _PLAN_REDUCE = {"min": jnp.min, "sum": jnp.sum, "max": jnp.max}
 
 
-def apply_reduce_plan(plan, cand, kind: str):
-    """Evaluate a reduce plan over per-edge candidates (jit-traceable:
-    every shape is static).  ``kind`` is min | sum | max; the max identity
-    is 0, so max plans are only valid for non-negative inputs (they are
-    used on 0/1 flags here)."""
-    tables, inv = plan
-    ident = jnp.asarray(_PLAN_IDENTITY[kind], cand.dtype)
-    ext = jnp.concatenate([cand, ident[None]])
+def _identity(x, kind: str):
+    leaves = jax.tree.leaves(x)
+    dtype = leaves[0].dtype if leaves else jnp.float32
+    return jnp.asarray(_PLAN_IDENTITY[kind], dtype)
+
+
+def plan_rows(plan: ReducePlan, x, kind: str):
+    """Each block's row results, concatenated in row order: ``x`` is the
+    tuple of table-order blocks, or the vector a plan over a vector gathers
+    from."""
+    if plan.take:
+        ext = jnp.concatenate([x, _identity(x, kind)[None]])
+        x = tuple(jnp.take(ext, t, axis=0) for t in plan.take)
     red = _PLAN_REDUCE[kind]
-    parts = [red(jnp.take(ext, t, axis=0), axis=1) for t in tables]
-    cat = jnp.concatenate(parts + [ident[None]])
-    return jnp.take(cat, inv, axis=0)
+    return jnp.concatenate([red(b, axis=ax) for b, ax in zip(x, plan.axes)])
+
+
+def apply_reduce_plan(plan: ReducePlan, x, kind: str):
+    """Evaluate a reduce plan (jit-traceable: every shape is static).  ``kind``
+    is min | sum | max; the max identity is 0, so max plans are only valid
+    for non-negative inputs (they are used on 0/1 flags here)."""
+    ident = _identity(x, kind)
+    if plan.whole:
+        return _PLAN_REDUCE[kind](x)[None]
+    if not plan.axes:
+        return jnp.full(plan.num_segments, ident)
+    rows = plan_rows(plan, x, kind)
+    return jnp.take(jnp.concatenate([rows, ident[None]]), plan.inv, axis=0)
+
+
+def _edge_gathers(src) -> int:
+    """Index elements of the per-edge value gather over table-order
+    source blocks."""
+    return sum(int(s.size) for s in src)
 
 
 # ---------------------------------------------------------------------------
@@ -236,30 +337,61 @@ def apply_reduce_plan(plan, cand, kind: str):
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("use_filter", "use_skip", "combine"))
-def _hitgraph_min_step(values, active, proc, src, delta, part, plans, *,
+def _min_candidates(values, src, delta):
+    """Per-slot min candidates ``values[src] + delta``, +inf in padding
+    (``delta``: table-order blocks or one scalar)."""
+    if not isinstance(delta, tuple):
+        delta = (delta,) * len(src)
+    return tuple(jnp.where(s >= 0, jnp.take(values, jnp.maximum(s, 0)) + d,
+                           jnp.inf) for s, d in zip(src, delta))
+
+
+def _acc_candidates(values, src, w):
+    """Per-slot acc candidates ``values[src] * w``, 0 in padding."""
+    return tuple(jnp.where(s >= 0, jnp.take(values, jnp.maximum(s, 0)) * ws,
+                           jnp.float32(0.0)) for s, ws in zip(src, w))
+
+
+@partial(jax.jit, static_argnames=("ivl", "use_filter", "use_skip",
+                                   "combine"))
+def _hitgraph_min_step(values, active, proc, src, delta, plans, *, ivl,
                        use_filter, use_skip, combine):
     """One HitGraph scatter+gather iteration, fused: global masked
     segment-min plus the per-destination-partition update counts the trace
-    assembly needs.  ``kept`` reproduces the model's update-filtering
-    (active-source bitmap) and partition-skipping masks; with update
-    combining the count per partition j is the number of (source
-    partition, destination) runs containing a kept edge — dst is sorted
-    within each routed block, so runs == unique destinations."""
-    kept = src >= 0
+    assembly needs.
+
+    The model's masks are properties of an edge's source, since partitions
+    are source intervals of ``ivl`` vertices: partition skipping keeps the
+    sources in processed partitions, update filtering the active sources.
+    So one vector carries the value and the mask together: the value of a
+    kept source and -1 for a dropped one (min-problem values are >= 0 or
+    +inf), and a slot is kept where its one gather reads >= 0.  Unreached
+    kept sources (+inf) still count as updates.  With update combining the
+    count per partition j is the number of (source partition, destination)
+    runs holding a kept edge: dst is sorted within each routed block, so
+    runs are the rows of the ``dst`` plan."""
+    n = values.shape[0]
+    keep = jnp.ones(n, dtype=bool)
     if use_skip:
-        kept &= jnp.take(proc, jnp.maximum(part, 0))
+        keep &= jnp.repeat(proc, ivl)[:n]
     if use_filter:
-        kept &= jnp.take(active, jnp.maximum(src, 0))
-    sv = jnp.take(values, jnp.maximum(src, 0))
-    cand = jnp.where(kept, sv + delta, jnp.inf)
-    acc = apply_reduce_plan(plans["dst"], cand, "min")
-    ki = kept.astype(jnp.int32)
-    if combine:
-        run_has = apply_reduce_plan(plans["run"], ki, "max")
-        nupd = apply_reduce_plan(plans["runj"], run_has, "sum")
+        keep &= active
+    enc = jnp.where(keep, values, -1.0)
+    sv = tuple(jnp.where(s >= 0, jnp.take(enc, jnp.maximum(s, 0)), -1.0)
+               for s in src)
+    kept = tuple(v >= 0 for v in sv)
+    cand = tuple(jnp.where(k, v + d, jnp.inf)
+                 for k, v, d in zip(kept, sv, delta))
+    if plans["up"] is None:
+        acc = apply_reduce_plan(plans["dst"], cand, "min")
     else:
-        nupd = apply_reduce_plan(plans["jid"], ki, "sum")
+        acc = apply_reduce_plan(plans["up"],
+                                plan_rows(plans["dst"], cand, "min"), "min")
+    runs = plan_rows(plans["dst"], tuple(k.astype(jnp.int32) for k in kept),
+                     "sum")
+    if combine:
+        runs = (runs > 0).astype(jnp.int32)
+    nupd = apply_reduce_plan(plans["jid"], runs, "sum")
     return jnp.minimum(values, acc), acc < values, nupd
 
 
@@ -268,9 +400,7 @@ def _jacobi_min_step(values, src, delta, plan):
     """ThunderGP's synchronous iteration: the per-(partition, chunk)
     partial accumulations combine to exactly the global segment-min
     (disjoint destination intervals, Jacobi source snapshot)."""
-    sv = jnp.take(values, jnp.maximum(src, 0))
-    cand = jnp.where(src >= 0, sv + delta, jnp.inf)
-    acc = apply_reduce_plan(plan, cand, "min")
+    acc = apply_reduce_plan(plan, _min_candidates(values, src, delta), "min")
     return jnp.minimum(values, acc), jnp.any(acc < values)
 
 
@@ -278,31 +408,26 @@ def _jacobi_min_step(values, src, delta, plan):
 def _acc_step(values, src, w, base, scale, plan):
     """Shared accumulation iteration: new = base + scale * A @ values,
     with A[dst, src] = w_eff."""
-    y = apply_reduce_plan(plan, w * jnp.take(values, src), "sum")
+    y = apply_reduce_plan(plan, _acc_candidates(values, src, w), "sum")
     return base + scale * y
 
 
 @jax.jit
-def _gs_min_step(values, esrc, ud, delta, plan):
+def _gs_min_step(values, esrc, delta, plan):
     """One AccuGraph partition under Gauss-Seidel (live values): segment
-    min over the partition's unique destinations.  Padding edges carry
-    cand=+inf and padding ud slots point at vertex 0 with acc=+inf, both
-    exact no-ops."""
-    sv = jnp.take(values, jnp.maximum(esrc, 0))
-    cand = jnp.where(esrc >= 0, sv + delta, jnp.inf)
-    acc = apply_reduce_plan(plan, cand, "min")
-    changed = acc < jnp.take(values, ud)
-    return values.at[ud].min(acc), changed
+    min over the destinations, +inf (a no-op) where the partition has no
+    edge.  The changed flags cover every vertex; the host reads them at
+    the partition's unique destinations."""
+    acc = apply_reduce_plan(plan, _min_candidates(values, esrc, delta), "min")
+    return jnp.minimum(values, acc), acc < values
 
 
 @jax.jit
-def _gs_acc_step(values, snapshot, esrc, ud, ew, scale, plan):
+def _gs_acc_step(values, snapshot, esrc, ew, scale, plan):
     """One AccuGraph partition of an accumulation iteration (reads the
     pre-iteration snapshot, adds into the base-initialised values)."""
-    sv = jnp.take(snapshot, jnp.maximum(esrc, 0))
-    cand = jnp.where(esrc >= 0, sv * ew, jnp.float32(0.0))
-    acc = apply_reduce_plan(plan, cand, "sum")
-    return values.at[ud].add(scale * acc)
+    acc = apply_reduce_plan(plan, _acc_candidates(snapshot, esrc, ew), "sum")
+    return values + scale * acc
 
 
 @jax.jit
@@ -315,9 +440,9 @@ def _fg_min_step(values, asrc, bsrc, csrc, delta, plans):
     values and per-interval changed flags (the dirty bits)."""
 
     def sub(v, s, plan):
-        sv = jnp.take(v, jnp.maximum(s, 0))
-        cand = jnp.where(s >= 0, sv + delta, jnp.inf)
-        acc = apply_reduce_plan(plan, cand, "min")
+        if not s:  # no shard in this group
+            return v, jnp.zeros(v.shape, dtype=bool)
+        acc = apply_reduce_plan(plan, _min_candidates(v, s, delta), "min")
         return jnp.minimum(v, acc), acc < v
 
     v1, c1 = sub(values, asrc, plans["a"])
@@ -339,49 +464,49 @@ def _build_hitgraph_min(g, problem, prep, k: int, ivl: int) -> dict:
         r = pi["route"]
         srcs.append(pi["src"][r])
         dsts.append(pi["dst"][r])
-        ps.append(np.full(len(r), i, dtype=np.int32))
+        ps.append(np.full(len(r), i, dtype=np.int64))
         if problem.name == "sssp":
             dls.append(pi["w"][r])
     gsrc = np.concatenate(srcs).astype(np.int32)
-    gdst = np.concatenate(dsts).astype(np.int32)
+    gdst = np.concatenate(dsts).astype(np.int64)
     gpart = np.concatenate(ps)
     m = len(gsrc)
     delta = (np.concatenate(dls).astype(np.float32) if dls
              else _min_delta(problem.name, None, m))
-    gjid = (gdst // ivl).astype(np.int32)
-    # runs of equal (source partition, destination) in routed order — the
-    # unit update combining collapses to (dst is ascending within each
-    # routed block when edge sorting is on, which combining requires)
-    if m:
-        change = np.empty(m, dtype=bool)
-        change[0] = True
-        change[1:] = (gdst[1:] != gdst[:-1]) | (gpart[1:] != gpart[:-1])
-        run_id = (np.cumsum(change) - 1).astype(np.int32)
-        runs = int(run_id[-1]) + 1
-        run_j = gjid[change]
+    # the plan's rows are the (source partition, destination) runs update
+    # combining collapses to; where no destination is reached from two
+    # partitions (always with one partition) they are the destinations
+    pairs, pair = np.unique(gpart * g.n + gdst, return_inverse=True)
+    if len(pairs) == len(np.unique(gdst)):
+        dst, slots = build_reduce_plan(gdst, g.n)
+        row_dst = row_segments(dst, slots, gdst)
+        up = None
     else:
-        run_id = np.zeros(0, dtype=np.int32)
-        runs = 1
-        run_j = np.zeros(0, dtype=np.int32)
-    L = _block_len(m)
-    # padding edges land in segment 0 / run 0 of each plan with kept=0
-    # candidates (inf for the min, 0 for the counts) — exact no-ops
-    plans = dict(
-        dst=build_reduce_plan(_pad_to(gdst, L, 0, np.int32), g.n),
-        run=build_reduce_plan(_pad_to(run_id, L, 0, np.int32), runs),
-        runj=build_reduce_plan(run_j, k),
-        jid=build_reduce_plan(_pad_to(gjid, L, 0, np.int32), k),
-    )
+        dst, slots = build_reduce_plan(pair, len(pairs))
+        row_dst = pairs[row_segments(dst, slots, pair)] % g.n
+        up, _ = build_reduce_plan(row_dst, g.n, over_vector=True)
+    jid, _ = build_reduce_plan(row_dst // ivl, k, over_vector=True)
+    src = table_order(slots, gsrc, -1, np.int32)
+    gathered = _edge_gathers(src) + jid.gathered() + (
+        dst.gathered() if up is None else up.gathered())
     return dict(
-        src=jnp.asarray(_pad_to(gsrc, L, -1, np.int32)),
-        delta=jnp.asarray(_pad_to(delta, L, 0.0, np.float32)),
-        part=jnp.asarray(_pad_to(gpart, L, 0, np.int32)),
-        plans=plans,
+        src=src,
+        delta=table_order(slots, delta, 0.0, np.float32),
+        plans=dict(dst=dst, up=up, jid=jid),
+        gathered=gathered,
     )
+
+
+def _acc_layout(g, problem, src, dst, w) -> dict:
+    """Table-order layout of one accumulation over every edge."""
+    w_eff = _acc_weight(problem.name, src, w, g.degrees_out)
+    plan, slots = build_reduce_plan(dst, g.n)
+    src_t = table_order(slots, src, -1, np.int32)
+    return dict(src=src_t, w=table_order(slots, w_eff, 0.0, np.float32),
+                plan=plan, gathered=_edge_gathers(src_t) + plan.gathered())
 
 
 def _build_hitgraph_acc(g, problem, parts, k: int, ivl: int) -> dict:
-    w_eff = _acc_weight(problem.name, g.src, g.weights, g.degrees_out)
     # static trace products: update counts and changed (written) vertex
     # sets per destination partition — value-independent for a single
     # accumulation iteration
@@ -394,9 +519,7 @@ def _build_hitgraph_acc(g, problem, parts, k: int, ivl: int) -> dict:
     cuts = np.searchsorted(ud_all, bounds)
     changed_j = [ud_all[cuts[j]: cuts[j + 1]] for j in range(k)]
     return dict(
-        src=jnp.asarray(g.src.astype(np.int32)),
-        w=jnp.asarray(w_eff),
-        plan=build_reduce_plan(g.dst, g.n),
+        _acc_layout(g, problem, g.src, g.dst, g.weights),
         nupd_plain=nupd_plain,
         nupd_combine=nupd_combine,
         changed_j=changed_j,
@@ -412,6 +535,7 @@ class HitGraphDevice:
         self.filter_opt = filter_opt
         self.skip_opt = skip_opt
         self.combine_opt = combine_opt
+        self.ivl = min(ivl, g.n)  # the vertices of a source partition
         if problem.kind == "min":
             self.lay = ARTIFACTS.get_or_build(
                 (g.fingerprint, "semexec.hitgraph", ivl, sort_opt, weighted,
@@ -431,10 +555,10 @@ class HitGraphDevice:
 
     def min_step(self, values_dev, active: np.ndarray, proc: np.ndarray):
         lay = self.lay
-        _STEPS["semexec_steps"] += 1
+        _dispatched(lay["gathered"])
         new, changed, nupd = _hitgraph_min_step(
             values_dev, jnp.asarray(active), jnp.asarray(proc),
-            lay["src"], lay["delta"], lay["part"], lay["plans"],
+            lay["src"], lay["delta"], lay["plans"], ivl=self.ivl,
             use_filter=self.filter_opt, use_skip=self.skip_opt,
             combine=self.combine_opt)
         with span("semexec_wait"):
@@ -442,7 +566,7 @@ class HitGraphDevice:
 
     def acc_step(self, values_dev):
         lay = self.lay
-        _STEPS["semexec_steps"] += 1
+        _dispatched(lay["gathered"])
         return _acc_step(values_dev, lay["src"], lay["w"], self.base,
                          self.scale, lay["plan"])
 
@@ -459,21 +583,20 @@ class HitGraphDevice:
 
 
 def _build_accugraph(g, problem, part_edges, k: int, ivl: int) -> dict:
-    esrc, ud, ew, plan = [], [], [], []
+    esrc, ew, plan, gathered = [], [], [], []
     ud_host, u_count = [], []
     for p in range(k):
-        src, _dst, udp, inv = part_edges[p]
-        E = _pow2(len(src))
-        U = _pow2(max(len(udp), 1), lo=1)
-        esrc.append(jnp.asarray(_pad_to(src, E, -1, np.int32)))
-        ud.append(jnp.asarray(_pad_to(udp, U, 0, np.int32)))
-        plan.append(build_reduce_plan(_pad_to(inv, E, 0, np.int32), U))
+        src, dst, udp, _inv = part_edges[p]
+        pl, slots = build_reduce_plan(dst, g.n)
+        esrc.append(table_order(slots, src, -1, np.int32))
+        plan.append(pl)
+        gathered.append(_edge_gathers(esrc[-1]) + pl.gathered())
         ud_host.append(np.asarray(udp))
         u_count.append(len(udp))
         if problem.kind == "acc":
             w_eff = _acc_weight(problem.name, src, None, g.degrees_out)
-            ew.append(jnp.asarray(_pad_to(w_eff, E, 0.0, np.float32)))
-    return dict(esrc=esrc, ud=ud, ew=ew, plan=plan,
+            ew.append(table_order(slots, w_eff, 0.0, np.float32))
+    return dict(esrc=esrc, ew=ew, plan=plan, gathered=gathered,
                 ud_host=ud_host, u_count=u_count)
 
 
@@ -497,20 +620,19 @@ class AccuGraphDevice:
         lay = self.lay
         if lay["u_count"][p] == 0:
             return values_dev, np.zeros(0, dtype=bool)
-        _STEPS["semexec_steps"] += 1
-        new, changed = _gs_min_step(values_dev, lay["esrc"][p],
-                                    lay["ud"][p], self.delta, lay["plan"][p])
+        _dispatched(lay["gathered"][p])
+        new, changed = _gs_min_step(values_dev, lay["esrc"][p], self.delta,
+                                    lay["plan"][p])
         with span("semexec_wait"):
-            return new, np.asarray(changed)[: lay["u_count"][p]]
+            return new, np.asarray(changed)[lay["ud_host"][p]]
 
     def acc_step(self, values_dev, snapshot_dev, p: int):
         lay = self.lay
         if lay["u_count"][p] == 0:
             return values_dev
-        _STEPS["semexec_steps"] += 1
+        _dispatched(lay["gathered"][p])
         return _gs_acc_step(values_dev, snapshot_dev, lay["esrc"][p],
-                            lay["ud"][p], lay["ew"][p], self.scale,
-                            lay["plan"][p])
+                            lay["ew"][p], self.scale, lay["plan"][p])
 
 
 # ---------------------------------------------------------------------------
@@ -522,29 +644,24 @@ def _build_thundergp(g, problem, prep, k: int, p: int, ivl: int) -> dict:
     srcs = [prep[i][c]["src"] for i in range(k) for c in range(p)]
     dsts = [prep[i][c]["dst"] for i in range(k) for c in range(p)]
     gsrc = np.concatenate(srcs).astype(np.int32)
-    gdst = np.concatenate(dsts).astype(np.int32)
+    gdst = np.concatenate(dsts).astype(np.int64)
     m = len(gsrc)
-    if problem.kind == "min":
-        if problem.name == "sssp":
-            w = np.concatenate(
-                [prep[i][c]["w"] for i in range(k) for c in range(p)])
-        else:
-            w = None
-        delta = _min_delta(problem.name, w, m)
-        L = _block_len(m)
-        return dict(
-            src=jnp.asarray(_pad_to(gsrc, L, -1, np.int32)),
-            delta=jnp.asarray(_pad_to(delta, L, 0.0, np.float32)),
-            plan=build_reduce_plan(_pad_to(gdst, L, 0, np.int32), g.n),
-        )
-    if problem.name == "spmv":
+    if problem.name in ("sssp", "spmv"):
         w = np.concatenate(
             [prep[i][c]["w"] for i in range(k) for c in range(p)])
     else:
         w = None
-    w_eff = _acc_weight(problem.name, gsrc, w, g.degrees_out)
-    return dict(src=jnp.asarray(gsrc), w=jnp.asarray(w_eff),
-                plan=build_reduce_plan(gdst, g.n))
+    if problem.kind == "acc":
+        return _acc_layout(g, problem, gsrc, gdst, w)
+    plan, slots = build_reduce_plan(gdst, g.n)
+    src = table_order(slots, gsrc, -1, np.int32)
+    return dict(
+        src=src,
+        delta=table_order(slots, _min_delta(problem.name, w, m), 0.0,
+                          np.float32),
+        plan=plan,
+        gathered=_edge_gathers(src) + plan.gathered(),
+    )
 
 
 class ThunderGPDevice:
@@ -564,7 +681,7 @@ class ThunderGPDevice:
 
     def min_step(self, values_dev):
         lay = self.lay
-        _STEPS["semexec_steps"] += 1
+        _dispatched(lay["gathered"])
         new, anyc = _jacobi_min_step(values_dev, lay["src"], lay["delta"],
                                      lay["plan"])
         with span("semexec_wait"):
@@ -572,7 +689,7 @@ class ThunderGPDevice:
 
     def acc_step(self, values_dev):
         lay = self.lay
-        _STEPS["semexec_steps"] += 1
+        _dispatched(lay["gathered"])
         return _acc_step(values_dev, lay["src"], lay["w"], self.base,
                          self.scale, lay["plan"])
 
@@ -584,35 +701,35 @@ class ThunderGPDevice:
 
 def _build_foregraph(g, problem, sizes, shard_edges, interval: int,
                      q: int) -> dict:
-    if problem.kind == "acc":
-        pairs = [shard_edges[(i, j)] for i in range(q) for j in range(q)
-                 if sizes[i, j]]
-        gsrc = (np.concatenate([s for s, _ in pairs]).astype(np.int32)
-                if pairs else np.zeros(0, dtype=np.int32))
-        gdst = (np.concatenate([d for _, d in pairs]).astype(np.int32)
-                if pairs else np.zeros(0, dtype=np.int32))
-        w_eff = _acc_weight(problem.name, gsrc, None, g.degrees_out)
-        return dict(src=jnp.asarray(gsrc), w=jnp.asarray(w_eff),
-                    plan=build_reduce_plan(gdst, g.n))
-
-    def pack(i: int, js: list[int]):
-        es = [shard_edges[(i, j)] for j in js if sizes[i, j]]
+    def edges(pairs):
+        es = [shard_edges[ij] for ij in pairs if sizes[ij]]
         src = (np.concatenate([s for s, _ in es]).astype(np.int32)
                if es else np.zeros(0, dtype=np.int32))
-        dst = (np.concatenate([d for _, d in es]).astype(np.int32)
-               if es else np.zeros(0, dtype=np.int32))
-        E = _pow2(len(src))
-        return (jnp.asarray(_pad_to(src, E, -1, np.int32)),
-                build_reduce_plan(_pad_to(dst, E, 0, np.int32), g.n))
+        dst = (np.concatenate([d for _, d in es]).astype(np.int64)
+               if es else np.zeros(0, dtype=np.int64))
+        return src, dst
 
-    ipq = build_reduce_plan((np.arange(g.n) // interval).astype(np.int32), q)
-    abc, plans = [], []
+    if problem.kind == "acc":
+        src, dst = edges([(i, j) for i in range(q) for j in range(q)])
+        return _acc_layout(g, problem, src, dst, None)
+
+    def pack(i: int, js: list[int]):
+        src, dst = edges([(i, j) for j in js])
+        plan, slots = build_reduce_plan(dst, g.n)
+        src_t = table_order(slots, src, -1, np.int32)
+        return src_t, plan, _edge_gathers(src_t) + plan.gathered()
+
+    ipq, _ = build_reduce_plan((np.arange(g.n) // interval), q,
+                               over_vector=True)
+    abc, plans, gathered = [], [], []
     for i in range(q):
-        (a, pa), (b, pb), (c, pc) = (pack(i, list(range(i))), pack(i, [i]),
-                                     pack(i, list(range(i + 1, q))))
+        (a, pa, ga), (b, pb, gb), (c, pc, gc) = (
+            pack(i, list(range(i))), pack(i, [i]),
+            pack(i, list(range(i + 1, q))))
         abc.append((a, b, c))
         plans.append(dict(a=pa, b=pb, c=pc, ipq=ipq))
-    return dict(abc=abc, plans=plans)
+        gathered.append(ga + gb + gc + ipq.gathered())
+    return dict(abc=abc, plans=plans, gathered=gathered)
 
 
 class ForeGraphDevice:
@@ -639,7 +756,7 @@ class ForeGraphDevice:
 
     def min_step(self, values_dev, i: int):
         lay = self.lay
-        _STEPS["semexec_steps"] += 1
+        _dispatched(lay["gathered"][i])
         new, flags = _fg_min_step(values_dev, *lay["abc"][i], self.delta,
                                   lay["plans"][i])
         with span("semexec_wait"):
@@ -647,6 +764,6 @@ class ForeGraphDevice:
 
     def acc_step(self, values_dev):
         lay = self.lay
-        _STEPS["semexec_steps"] += 1
+        _dispatched(lay["gathered"])
         return _acc_step(values_dev, lay["src"], lay["w"], self.base,
                          self.scale, lay["plan"])

@@ -16,6 +16,7 @@ PE/channel count and the interval scale.
 from __future__ import annotations
 
 import warnings
+from functools import partial
 
 import numpy as np
 import pytest
@@ -147,30 +148,68 @@ def test_emit_bank_row_device_matches_trace_batch(mapping, tiny_graph):
     np.testing.assert_array_equal(lengths, ref.lengths)
 
 
+STEP_PROGRAMS = ("_hitgraph_min_step", "_jacobi_min_step", "_acc_step",
+                 "_gs_min_step", "_gs_acc_step", "_fg_min_step")
+
+
+def _gathers(jaxpr) -> tuple[int, int]:
+    """Index elements the gathers of ``jaxpr`` (and of the jaxprs it
+    calls) read, and its scatters."""
+    gathered = scatters = 0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather":
+            gathered += int(np.prod(eqn.invars[1].aval.shape[:-1]))
+        scatters += name.startswith("scatter")
+        for sub in eqn.params.values():
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                g, s = _gathers(sub)
+                gathered, scatters = gathered + g, scatters + s
+    return gathered, scatters
+
+
 @pytest.mark.parametrize("accel", sorted(semexec.SUPPORTED))
 def test_device_steps_reduce_through_plans(accel, monkeypatch):
     """Every backend runs one device program: each step reduces through
-    its reduce plans (no Pallas kernel, no scatter kernel), and the
-    simulated result equals the numpy engine's."""
+    its table-order reduce plans (no Pallas kernel, no scatter), gathers
+    no more index elements than its layout counts in
+    ``semexec_gathered``, and the simulated result equals the numpy
+    engine's."""
     import jax
 
     g = GraphSpec("plans", "uniform", 300, 1500, True, 3, 0).build()
-    kinds = []
+    kinds, calls = [], []
     real = semexec.apply_reduce_plan
 
     def counting(plan, cand, kind):
         kinds.append(kind)
         return real(plan, cand, kind)
 
+    def recording(step):
+        def call(*args, **kwargs):
+            calls.append((step, args, kwargs))
+            return step(*args, **kwargs)
+        return call
+
     monkeypatch.setattr(semexec, "apply_reduce_plan", counting)
+    for name in STEP_PROGRAMS:
+        monkeypatch.setattr(semexec, name, recording(getattr(semexec, name)))
     for prob in ("bfs", "pr"):
         host = _prepare(accel, g, prob, "numpy")
         kinds.clear()
+        calls.clear()
         jax.clear_caches()  # retrace the steps, so their bodies run here
+        before = semexec.step_stats()["semexec_gathered"]
         dev = _prepare(accel, g, prob, "device")
         assert dev.layout["engine"] == "device"
         assert kinds and set(kinds) <= {"min", "sum", "max"}
         assert ("min" in kinds) == (PROBLEMS[prob].kind == "min")
+        traced = [_gathers(jax.make_jaxpr(partial(step, **kw))(*args).jaxpr)
+                  for step, args, kw in calls]
+        assert calls and all(s == 0 for _, s in traced)
+        assert (sum(n for n, _ in traced)
+                == semexec.step_stats()["semexec_gathered"] - before)
         rep_h, rep_d = host.finalize(), dev.finalize()
         assert rep_d.timing == rep_h.timing
         assert rep_d.iterations == rep_h.iterations
@@ -194,3 +233,32 @@ def test_step_counter_counts_each_device_step(accel, kron_graph):
     assert dev.iterations == host.iterations > 1
     _prepare(accel, g, "sssp", "device")  # a semantics-cache hit
     assert semexec.step_stats()["semexec_steps"] == before + dev.iterations
+
+
+@pytest.mark.parametrize("accel", sorted(semexec.SUPPORTED))
+def test_gathered_counter_advances_by_layout_count(accel, kron_graph):
+    """``semexec_gathered`` grows at each dispatched step by the layout's
+    static count, and not at all on the numpy engine or when the semantics
+    cache serves the execution.  With one interval every model's step
+    gathers the same: the source values of the destination plan's slots
+    and the plan's gather over the vertices."""
+    from repro.core.hostcache import SEMANTICS
+
+    SEMANTICS.clear()
+    g = kron_graph
+    _, slots = semexec.build_reduce_plan(g.dst, g.n)
+    per_step = sum(s.size for s in slots) + g.n
+
+    def counters():
+        st = semexec.step_stats()
+        return st["semexec_steps"], st["semexec_gathered"]
+
+    steps0, gathered0 = counters()
+    _prepare(accel, g, "bfs", "numpy", interval_size=4096)
+    assert counters() == (steps0, gathered0)
+    _prepare(accel, g, "bfs", "device", interval_size=4096)
+    steps1, gathered1 = counters()
+    assert steps1 > steps0
+    assert gathered1 - gathered0 == (steps1 - steps0) * per_step
+    _prepare(accel, g, "bfs", "device", interval_size=4096)  # a cache hit
+    assert counters() == (steps1, gathered1)

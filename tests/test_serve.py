@@ -618,9 +618,10 @@ def test_sigterm_drains_and_resume_completes(tmp_path):
 
 
 def test_scheduler_counts_semexec_steps(tmp_path):
-    """Seats report the device semantic engine's jitted steps; over a job
-    of fresh SSSP executions the counter grows by the rows' iterations
-    (one step an iteration for HitGraph and ThunderGP)."""
+    """Seats report the device semantic engine's jitted steps and what
+    they gather; over a job of fresh SSSP executions the step counter
+    grows by the rows' iterations (one step an iteration for HitGraph and
+    ThunderGP), the gather counter by each step's static count."""
     kron = GraphSpec("kron-steps", "rmat", 512, 8192, False, 23, 5)
     sched = scheduler(tmp_path, GatedPool(), chunk_size=2)
     try:
@@ -634,5 +635,13 @@ def test_scheduler_counts_semexec_steps(tmp_path):
         assert steps > 2
         c = sched.stats()["counters"]
         assert c["worker_device_semexec_steps"] == steps
+        # one interval: both models' steps gather the destination plan's
+        # slots and its gather over the vertices
+        from repro.core import semexec
+
+        g = kron.build()
+        _, slots = semexec.build_reduce_plan(g.dst, g.n)
+        per_step = sum(s.size for s in slots) + g.n
+        assert c["worker_device_semexec_gathered"] == steps * per_step
     finally:
         sched.close()
